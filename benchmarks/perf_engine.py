@@ -175,6 +175,7 @@ from repro.core.sa import SAConfig
 from repro.core.tiers import GH200
 from repro.kvcache.migrate import MigrationPlan, apply_migrations
 from repro.kvcache.paged import prefill_cache
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import control, trace_bridge
 from repro.serving.engine import EngineConfig, ServingEngine
@@ -1218,7 +1219,9 @@ def run_policy_sweep(print_csv: bool = True, steps: int = STEPS):
     return sweep, serve_sweep
 
 
-if __name__ == "__main__":
+def main(argv=None) -> None:
+    """Command line: one leg per flag (the full engine benchmark by
+    default)."""
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=STEPS)
@@ -1243,7 +1246,8 @@ if __name__ == "__main__":
                          "(per-policy goodput-under-SLO curves on the "
                          "seeded mixed Poisson+bursty stream, one "
                          "executable across arrival patterns)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.goodput_sweep:
         run_goodput_sweep(ci=args.ci)
     elif args.overlap_sweep:
@@ -1254,3 +1258,7 @@ if __name__ == "__main__":
         run_policy_sweep(steps=args.steps)
     else:
         run(steps=args.steps, ci=args.ci)
+
+
+if __name__ == "__main__":
+    main()

@@ -106,6 +106,23 @@ TPU_V6E = MemorySystemSpec(
 
 SPECS = {s.name: s for s in (GH200, TPU_V5E, TPU_V5P, TPU_V6E)}
 
+#: the modeled memory system of each device JAX can report
+#: (`device.device_kind`). A CPU run models the paper's own GH200.
+SPEC_BY_DEVICE_KIND = {
+    "TPU v5 lite": "tpu_v5e",
+    "cpu": "gh200",
+}
+
+
+def spec_for_device(device) -> MemorySystemSpec:
+    """The spec modeling `device`; an unlisted kind is an error."""
+    kind = device.device_kind
+    if kind not in SPEC_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no modeled memory system for device kind {kind!r}; known: "
+            f"{sorted(SPEC_BY_DEVICE_KIND)} (pass a spec explicitly)")
+    return SPECS[SPEC_BY_DEVICE_KIND[kind]]
+
 
 # --- Compute-roofline constants for the dry-run target (v5e) ----------------
 @dataclasses.dataclass(frozen=True)

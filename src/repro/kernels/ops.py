@@ -13,6 +13,7 @@ the hot path — it is Python-slow).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -31,17 +32,48 @@ def tier_attention(q, k_pool, v_pool, page_list, page_valid,
                    *, use_pallas: Optional[bool] = None):
     """Partial attention over one tier -> (out, m, l, page_lse).
 
-    TPU: the Pallas paged kernel (page-table gather in SMEM).
+    TPU: the Pallas paged kernel (page-table gather in SMEM), run per
+    shard under `shard_map` when traced inside a mesh context (GSPMD
+    cannot partition a Mosaic kernel).
     Otherwise: the gather-free dense pool form — page_list from
     `tier_lists` is identity-or-hole, holes already have valid == 0,
     so masking alone is exact, and GSPMD keeps pools page-sharded.
     """
     if use_pallas is None:
         use_pallas = _on_tpu()
-    if use_pallas:
-        return paged_attention(q, k_pool, v_pool, page_list, page_valid,
-                               interpret=not _on_tpu())
-    return ref.pool_attention_ref(q, k_pool, v_pool, page_valid)
+    if not use_pallas:
+        return ref.pool_attention_ref(q, k_pool, v_pool, page_valid)
+    kernel = functools.partial(paged_attention, interpret=not _on_tpu())
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(q, k_pool, v_pool, page_list, page_valid)
+    return _shard_kernel(kernel, mesh, q, k_pool, v_pool, page_list,
+                         page_valid)
+
+
+def _shard_kernel(kernel, mesh, q, k_pool, v_pool, page_list, page_valid):
+    """The paged kernel per shard: lanes over the batch axes, kv_heads
+    over `model` — the cache layout `launch.shardings.cache_shardings`
+    picks whenever kv_heads divides the model axis. Each shard's heads
+    are independent, so no collective runs inside."""
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.shardings import batch_axes
+    B, KH = q.shape[0], q.shape[1]
+    m = dict(mesh.shape).get("model", 1)
+    if KH % m:
+        raise NotImplementedError(
+            f"the Pallas paged kernel shards kv_heads over 'model'; "
+            f"kv_heads={KH} does not divide model={m} (page-sharded "
+            f"pools need a cross-shard LSE merge)")
+    b = batch_axes(mesh, B)
+    h = "model" if m > 1 else None
+    heads = P(b, h, None, None)
+    pool = P(b, None, None, h, None)
+    lane = P(b, None)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(heads, pool, pool, lane, lane),
+        out_specs=(heads, P(b, h, None), P(b, h, None), heads),
+        check_vma=False)(q, k_pool, v_pool, page_list, page_valid)
 
 
 def tiered_paged_attention(

@@ -19,12 +19,12 @@ pipeline (`EngineConfig.overlap_migrations`) threads a staged
 later, concurrently with the next step's decode compute; hazard masking
 for that lag lives in `repro.serving.control.revalidate_plan`.
 
-On a real TPU the two pools live in different `memory_kind`s
-(`repro.kvcache.paged.host_memory_kind` feature-detects pinned host
-memory) and XLA lowers the cross-pool scatter into DMA transfers over
-the host link — the M_i / M_o traffic of Eq. (3)/(4). The byte
-accounting used by the simulator and by the engine's telemetry matches
-1:1.
+Both pools are ordinary device arrays, so on a TPU the cross-pool
+scatter is an HBM-to-HBM copy: the DRAM tier is HBM-resident until the
+host pool moves to host memory (ROADMAP queue 1 item 2), and only then
+does a move become a transfer over the host link — the M_i / M_o
+traffic of Eq. (3)/(4). The byte accounting used by the simulator and
+by the engine's telemetry already counts those moves 1:1.
 """
 
 from __future__ import annotations

@@ -12,16 +12,14 @@ Logical pages are mapped to physical slots by a single page table:
                 slot >= hbm_pages -> host slot (slot - hbm_pages),
                 NO_SLOT (=-1)     -> page not allocated yet.
 
-On real TPU/GPU hardware the host pool is a `memory_kind="pinned_host"`
-array and page migration is a device_put between pools; on CPU (tests,
-dry-run) both pools are ordinary arrays but the data path — page tables,
-tier-split attention, migration traffic accounting — is identical.
-`host_memory_kind()` feature-detects pinned host memory so callers can
-gate the placement (`init_cache(geo, host_kind=...)`) without baking a
-backend assumption into the control plane; the serving engine probes it
-once at construction and applies it only under
-`EngineConfig.overlap_migrations`, where the staged commit's cross-pool
-scatter lowers to an async DMA the decode compute hides.
+Both pools are ordinary device arrays on every backend. On a TPU the
+"DRAM tier" is therefore HBM-resident: the data path — page tables,
+tier-split attention, migration traffic accounting — is the paper's,
+but a tier crossing is an HBM-to-HBM copy, not a host-link DMA, until
+the host pool moves to host memory and the kernel reads it page by
+page (ROADMAP queue 1 item 2). Attention never reads a host-memory
+operand directly: XLA refuses a `dot_general` whose inputs live in
+different memory spaces.
 
 The control plane (which page lives where) is host-side python in
 `repro.serving.engine`; everything in this module is jit-safe.
@@ -137,49 +135,17 @@ class PagedKVCache:
         return hl, hv, el, ev
 
 
-def host_memory_kind():
-    """The pinned host `memory_kind` the default backend advertises, or
-    None when it has no distinct host memory space (CPU, and runtimes
-    predating memory-kind support).
-
-    The capability gate for `pinned_host`-backed host pools: a positive
-    probe means `jax.device_put` between the pools is a real DMA over
-    the host link and XLA can overlap it with compute; a None keeps the
-    host pool an ordinary device array — bitwise the same data path,
-    just without the placement. Pure feature detection, no config."""
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:          # old runtimes: no memories() API
-        return None
-    return "pinned_host" if "pinned_host" in kinds else None
-
-
-def init_cache(geo: CacheGeometry, *, host_kind=None) -> PagedKVCache:
-    """A fresh all-free cache for `geo`.
-
-    `host_kind` (optional, from `host_memory_kind()`): place the host
-    pools in that memory kind — `"pinned_host"` on real TPU/GPU puts
-    the DRAM tier in pinned host memory so tier crossings are true
-    host-link DMAs. None (the CPU/test default) keeps every pool an
-    ordinary array; all shapes, dtypes, and values are identical either
-    way."""
+def init_cache(geo: CacheGeometry) -> PagedKVCache:
+    """A fresh all-free cache for `geo`."""
     L, B, T = geo.num_layers, geo.batch, geo.page_tokens
     kh, hd = geo.kv_heads, geo.head_dim
     shape_h = (L, B, geo.hbm_pages, T, kh, hd)
     shape_e = (L, B, geo.host_pages, T, kh, hd)
-    k_host = jnp.zeros(shape_e, geo.dtype)
-    v_host = jnp.zeros(shape_e, geo.dtype)
-    if host_kind is not None:
-        sh = jax.sharding.SingleDeviceSharding(jax.devices()[0],
-                                               memory_kind=host_kind)
-        k_host = jax.device_put(k_host, sh)
-        v_host = jax.device_put(v_host, sh)
     return PagedKVCache(
         k_hbm=jnp.zeros(shape_h, geo.dtype),
         v_hbm=jnp.zeros(shape_h, geo.dtype),
-        k_host=k_host,
-        v_host=v_host,
+        k_host=jnp.zeros(shape_e, geo.dtype),
+        v_host=jnp.zeros(shape_e, geo.dtype),
         page_table=jnp.full((L, B, geo.max_pages), NO_SLOT, jnp.int32),
         hbm_owner=jnp.full((L, B, geo.hbm_pages), NO_SLOT, jnp.int32),
         host_owner=jnp.full((L, B, geo.host_pages), NO_SLOT, jnp.int32),

@@ -8,6 +8,14 @@ and benches must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: the serve and dry-run programs
+    leave partitioning to GSPMD (jax >= 0.9 defaults to Explicit axes,
+    under which their gathers raise `ShardingTypeError`)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     the dry-run cost tables assume."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
@@ -26,7 +34,7 @@ def make_test_mesh(data: int = 1, model: int = 1):
     (set BEFORE jax initializes) fakes N host devices — how CI and the
     README's "Scaling out" quickstart exercise the sharded serve loop
     without accelerators."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -28,7 +28,8 @@ import numpy as np
 
 from repro import configs
 from repro.core.sa import SAConfig
-from repro.core.tiers import SPECS
+from repro.core.tiers import SPECS, spec_for_device
+from repro.launch.jax_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.model import Model
 from repro.serving import trace_bridge
@@ -78,7 +79,7 @@ def run_stream(model, params, args, mesh, *, trace: bool = False):
     cfg = EngineConfig(
         max_context=args.prompt_len + 32 + args.new_tokens + 16,
         hbm_fraction=args.hbm_fraction, policy=args.policy,
-        attention_sparsity=args.sparsity, spec=SPECS[args.spec],
+        attention_sparsity=args.sparsity, spec=args.spec,
         telemetry_stride=args.stride, prefill_chunk=16,
         trace_telemetry=trace)
     eng = ServingEngine(model, params, cfg, mesh=mesh)
@@ -117,12 +118,11 @@ def check_parity(model, params, args, mesh) -> bool:
                   f"  meshed:   {got_out.get(rid)}")
             ok = False
     sa_cfg = SAConfig(max_evaluations=6, iters_per_level=2, seed=0)
-    spec = SPECS[args.spec]
     frac = {}
     for tag, eng, rep in (("1dev", ref_eng, ref),
                           ("mesh", mesh_eng, got)):
         score = trace_bridge.score_serve(
-            trace_bridge.collect_serve(eng), spec, sa_cfg=sa_cfg,
+            trace_bridge.collect_serve(eng), args.spec, sa_cfg=sa_cfg,
             report=rep)
         agg = score["aggregate"]
         frac[tag] = (agg["live_hit_fraction"],
@@ -152,7 +152,10 @@ def main(argv=None) -> int:
                     choices=list(policy_names()))
     ap.add_argument("--sparsity", type=float, default=0.0)
     ap.add_argument("--hbm-fraction", type=float, default=0.25)
-    ap.add_argument("--spec", default="gh200", choices=list(SPECS))
+    ap.add_argument("--spec", default=None, choices=list(SPECS),
+                    help="modeled memory system (default: the one "
+                         "the device kind maps to, core.tiers."
+                         "SPEC_BY_DEVICE_KIND)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=24)
@@ -168,6 +171,9 @@ def main(argv=None) -> int:
                          "data=2,model=2), check tokens/statuses/"
                          "fractions match; exit 1 on divergence")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    args.spec = (SPECS[args.spec] if args.spec
+                 else spec_for_device(jax.devices()[0]))
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))

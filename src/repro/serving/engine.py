@@ -89,7 +89,7 @@ from repro.core.latency_model import StepTraffic, step_latency
 from repro.core.tiers import MemorySystemSpec, TPU_V5E
 from repro.kvcache.migrate import MigrationPlan, apply_migrations
 from repro.kvcache.paged import (
-    PagedKVCache, abstract_cache, host_memory_kind, init_cache,
+    PagedKVCache, abstract_cache, init_cache,
 )
 from repro.models.model import Model
 from repro.serving import control
@@ -376,13 +376,6 @@ class ServingEngine:
         #: it (`_ensure_step_fns`), but a Mesh is device state, not a
         #: serializable config value.
         self.mesh = mesh
-        #: feature-detected pinned host memory kind ("pinned_host" on
-        #: real TPU/GPU runtimes, None on CPU) — probed ONCE at
-        #: construction; overlap-mode serve places its host pools there
-        #: (single-device streams only: a mesh pins its own shardings)
-        #: so the staged commit's cross-pool scatter is a true host-link
-        #: DMA the decode compute hides.
-        self._host_memory_kind = host_memory_kind()
         self.stats: List[StepStats] = []
         self._sampling = SamplingConfig()
         #: raw (stats, access, tier) chunks when cfg.trace_telemetry
@@ -575,9 +568,7 @@ class ServingEngine:
             Pb = cfg.prefill_budget
             use_budget = Pb is not None
             pf_logits_sds, _ = jax.eval_shape(
-                lambda c, t, s, n: model.prefill_chunk(self.params, c,
-                                                       t, s, n),
-                abstract_cache(geo),
+                model.prefill_chunk, self.params, abstract_cache(geo),
                 jax.ShapeDtypeStruct((B, C), jnp.int32),
                 jax.ShapeDtypeStruct((B,), jnp.int32),
                 jax.ShapeDtypeStruct((B,), jnp.int32))
@@ -876,7 +867,14 @@ class ServingEngine:
                       rep, step_lane, step_lane, step_lane, step_lane,
                       None)
             donate = (1, 2)
-        self._serve_jit = jax.jit(serve_chunk_fn, donate_argnums=donate,
+
+        def meshed_chunk_fn(*args):
+            # the trace sees the mesh, so `ops.tier_attention` can run
+            # the Pallas kernel per shard (GSPMD cannot partition it)
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return serve_chunk_fn(*args)
+
+        self._serve_jit = jax.jit(meshed_chunk_fn, donate_argnums=donate,
                                   in_shardings=in_sh,
                                   out_shardings=out_sh)
         self._release_jit = jax.jit(control.release_lanes,
@@ -1077,14 +1075,9 @@ class ServingEngine:
         geo = self.model.cache_geometry(
             B, cfg.max_context, hbm_fraction=cfg.hbm_fraction)
         self.geo = geo
-        # overlap mode backs the host-tier pools with pinned_host
-        # memory when the platform exposes it (TPU/GPU) so the staged
-        # commit's gathers become true async DMA; single-device only —
-        # under a mesh the cache shardings own placement. On CPU
-        # `host_memory_kind()` is None and this is the plain init.
-        host_kind = self._host_memory_kind \
-            if (cfg.overlap_migrations and self.mesh is None) else None
-        self.state = init_cache(geo, host_kind=host_kind)
+        # both tiers are device arrays in every mode: on a TPU the host
+        # tier is HBM-resident until ROADMAP queue 1 item 2 lands
+        self.state = init_cache(geo)
         self.stats = []
         self._sampling = sampling or SamplingConfig()
         self._ensure_step_fns()
@@ -1556,8 +1549,7 @@ class ServingEngine:
             lay, bat, jnp.asarray(pro_dst), jnp.asarray(pro_src),
             jnp.asarray((r + 1) % geo.max_pages))
         empty = MigrationPlan.empty(cap)
-        host_kind = self._host_memory_kind if self.mesh is None else None
-        cache = init_cache(geo, host_kind=host_kind)
+        cache = init_cache(geo)
         # jit a LOCAL wrapper, not `apply_migrations` itself: jax's
         # tracing cache keys on the wrapped function object, so jitting
         # the module-level function here would leave this measurement's
@@ -1591,6 +1583,36 @@ class ServingEngine:
         spec = dataclasses.replace(base, name=base.name + "+measured",
                                    link_bw=link_bw)
         return spec, detail
+
+    def serve_chunk_shapes(self, num_slots: int, sharding=None) -> tuple:
+        """The argument shapes of one inline serve-chunk call for
+        `num_slots` lanes (what `serve()` passes `_serve_jit`), after
+        building the step functions for that geometry with greedy
+        sampling. `self._serve_jit.lower(*shapes).compile()` then
+        compiles the chunk ahead of a stream, for example for a
+        described chip (`sharding` on one of its devices) whose arrays
+        cannot exist here. `self.params` may be concrete or shapes."""
+        cfg = self.cfg
+        self.geo = self.model.cache_geometry(
+            num_slots, cfg.max_context, hbm_fraction=cfg.hbm_fraction)
+        self._sampling = SamplingConfig()
+        self._ensure_step_fns()
+        geo, B, S = self.geo, num_slots, max(1, cfg.telemetry_stride)
+
+        def like(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        def vec(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        pstate = jax.eval_shape(lambda: self._policy.init_state(geo))
+        return (jax.tree.map(like, self.params),
+                jax.tree.map(like, abstract_cache(geo)),
+                jax.tree.map(like, pstate),
+                vec((B,)), vec((B,), bool), vec((B,)),      # token/active/rem
+                vec((B, 2), jnp.uint32), vec((B,)), vec((B,)),  # keys/prog/len
+                vec((B, geo.max_tokens)), vec(()),          # prompts/credits
+                vec((S,)), vec((S, B), bool))               # caps/poison
 
     def _admit_lane(self, req: Request, hs: Dict) -> None:
         """Bind an admitted request to its cache lane for CHUNKED

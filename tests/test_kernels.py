@@ -1,6 +1,7 @@
 """Pallas kernel correctness: shape/dtype sweeps vs the pure-jnp oracles
 (interpret mode executes the kernel body on CPU)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,6 +28,8 @@ PAGED_SHAPES = [
     (2, 2, 8, 128, 16, 16, 16),   # qwen3-like G=8
     (1, 8, 1, 64, 8, 16, 8),      # zamba2-like MHA
     (3, 2, 5, 128, 8, 16, 5),     # llama4-like G=5
+    (2, 8, 2, 128, 12, 16, 12),   # internlm2-1.8b widths (the chip's layout)
+    (1, 8, 2, 128, 8, 16, 136),   # page LSEs span two 128-page blocks
 ]
 
 
@@ -57,6 +60,27 @@ class TestPagedAttentionKernel:
         o, m, l, lse = paged_attention(q, kp, vp, pl, pv, interpret=True)
         assert np.all(np.asarray(l) == 0.0)
         assert np.all(np.isfinite(np.asarray(o)))
+
+    def test_sharded_kernel_under_mesh_context(self):
+        """`ops.tier_attention` runs the kernel under shard_map when the
+        trace carries a mesh (as the engine's meshed serve jit does):
+        same results as the oracle."""
+        from repro.kernels import ops
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh(data=1, model=1)
+        rng = np.random.default_rng(2)
+        q, kp, vp, pl, pv = _rand_paged(rng, 2, 4, 2, 64, 6, 16, 6,
+                                        jnp.float32)
+
+        def f(*args):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return ops.tier_attention(*args, use_pallas=True)
+
+        got = jax.jit(f)(q, kp, vp, pl, pv)
+        want = ref.paged_attention_ref(q, kp, vp, pl, pv)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-4)
 
     def test_pool_attention_matches_identity_paged(self):
         """Gather-free SPMD path == paged oracle with identity layout."""

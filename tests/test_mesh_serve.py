@@ -13,6 +13,7 @@ its own 4-device interpreter, XLA_FLAGS must precede jax init) keeps
 the parity contract in tier-1 everywhere.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -21,6 +22,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro import configs
 from repro.core.sa import SAConfig
@@ -40,7 +42,11 @@ needs_mesh = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def model_params():
-    cfg = configs.get_smoke("internlm2-1.8b")
+    # f32: in bf16 the model axis's partial sums round differently from
+    # the 1-device matmul, and greedy near-ties of the random smoke
+    # model flip; the pins here are about partitioning, not rounding
+    cfg = dataclasses.replace(configs.get_smoke("internlm2-1.8b"),
+                              dtype=jnp.float32, param_dtype=jnp.float32)
     model = Model(cfg)
     return model, model.init(jax.random.key(0))
 

@@ -16,7 +16,7 @@ from repro.launch import shardings as shd
 
 
 def am(**sizes):
-    return AbstractMesh(tuple(sizes.items()))
+    return AbstractMesh(tuple(sizes.values()), tuple(sizes))
 
 
 def geo_stub(*, kv_heads=2, hbm_pages=16, host_pages=16, batch=4,
