@@ -12,14 +12,19 @@ prefilled cache. The last line of standard output is one JSON object.
     python chip_smoke.py                # one chip
     python chip_smoke.py --four-chips   # 1 device vs data=2,model=2
 
-`--four-chips` runs only the mesh path and what it is compared with:
-the same stream served on one device and on a data=2,model=2 mesh over
-four chips, with tokens and statuses required to be identical. It runs
-in float32 with full-precision matmuls: the model axis sums partial
-products in another order than the 1-device matmul, and in bf16, or in
-float32 at the TPU's default one-pass bf16 matmul precision, those
-last-bit differences grow over 24 layers until a greedy near-tie of the
-random weights flips.
+`--four-chips` runs only the mesh path and what it is compared with,
+on a data=2,model=2 mesh over four chips against one device:
+
+- float32 with full-precision matmuls: the same stream served on both,
+  tokens and statuses required to be identical. The model axis sums
+  partial products in another order than the 1-device matmul; in bf16,
+  or in float32 at the TPU's default one-pass bf16 matmul precision,
+  those last-bit differences grow over 24 layers until a greedy
+  near-tie of the random weights flips;
+- bf16, the dtype users serve (the float32 weights rounded): one
+  decode step on the mesh against one device on the same prefilled
+  cache, logits within `LOGIT_RTOL`, and the meshed serve of the stream
+  with every request ok on one executable that holds the kernel.
 
 With no TPU (for example under JAX_PLATFORMS=cpu) it exits non-zero
 and prints no result line.
@@ -48,17 +53,19 @@ from repro.kernels import ops, ref  # noqa: E402
 from repro.kvcache.paged import abstract_cache  # noqa: E402
 from repro.launch.jax_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_test_mesh  # noqa: E402
+from repro.launch.serve import decode_step_logits  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.serving.engine import EngineConfig, ServingEngine  # noqa: E402
 from repro.serving.scheduler import Request  # noqa: E402
 
 ARCH = "internlm2-1.8b"
-#: Pallas vs reference decode logits, as a share of the largest
-#: reference logit. The two paths round to bf16 (8 significant bits)
-#: at different points of each of the 24 layers' attention, and the
-#: random-weight residual stream carries those differences to the
-#: logits; a wrong head, page or mask moves logits by O(1) of the
-#: largest one. The tight check is the kernel's on real pools below.
+#: bf16 decode logits of two paths that should agree (Pallas vs
+#: reference attention, or a data=2,model=2 mesh vs one device), as a
+#: share of the largest reference logit. The two paths round to bf16
+#: (8 significant bits) at different points of each of the 24 layers,
+#: and the random-weight residual stream carries those differences to
+#: the logits; a wrong head, page, mask or shard moves logits by O(1)
+#: of the largest one. The tight check is the kernel's on real pools.
 LOGIT_RTOL = 1 / 8
 #: kernel vs float32 reference on real prefilled pools (the interpret
 #: mode test tolerances of tests/test_kernels.py)
@@ -233,29 +240,44 @@ def run_one_chip(seed: int) -> None:
 
 
 def run_four_chips(seed: int) -> None:
-    """One device vs a data=2,model=2 mesh over four chips, same stream,
-    float32 at full matmul precision (see the module docstring)."""
+    """One device vs a data=2,model=2 mesh over four chips: float32
+    token parity, then bf16 (see the module docstring)."""
     require(jax.device_count() >= 4,
             f"--four-chips needs 4 devices, found {jax.device_count()}")
     with jax.default_matmul_precision("highest"):
-        _four_chip_parity(seed)
+        params32 = _four_chip_parity(seed)
+    model = Model(configs.get(ARCH))
+    params = jax.device_put(jax.tree.map(
+        lambda x, a: x.astype(a.dtype), params32, model.abstract_params()),
+        jax.devices()[0])
+    del params32
+    _four_chip_bf16(model, params, seed)
 
 
-def _four_chip_parity(seed: int) -> None:
-    cfg = dataclasses.replace(configs.get(ARCH), dtype=jnp.float32,
-                              param_dtype=jnp.float32)
-    model = Model(cfg)
-    params = init_params(model, seed)
-    log(f"weights: {nbytes(params)} bytes ({cfg.name}, float32)")
-    B = 4                     # float32 weights and cache fit one chip
+def _four_chip_config(model: Model, seed: int):
+    """Engine config, lanes and request stream of both legs: float32
+    weights and cache fit one chip at B=4 and max_context 1024."""
+    B = 4
     ecfg = EngineConfig(max_context=1024, hbm_fraction=0.25,
                         spec=spec_for_device(jax.devices()[0]),
                         prefill_chunk=64, telemetry_stride=32)
     geo = model.cache_geometry(B, ecfg.max_context,
                                hbm_fraction=ecfg.hbm_fraction)
     hbm_tokens = geo.hbm_pages * geo.page_tokens
-    reqs = functools.partial(make_requests, cfg.vocab, 8, 128, 768,
+    reqs = functools.partial(make_requests, model.cfg.vocab, 8, 128, 768,
                              hbm_tokens, 4, 32, 48, seed)
+    return B, ecfg, geo, reqs
+
+
+def _four_chip_parity(seed: int):
+    """float32: the same stream on one device and on the mesh, tokens
+    and statuses identical. Returns the mesh-placed weights."""
+    cfg = dataclasses.replace(configs.get(ARCH), dtype=jnp.float32,
+                              param_dtype=jnp.float32)
+    model = Model(cfg)
+    params = init_params(model, seed)
+    log(f"weights: {nbytes(params)} bytes ({cfg.name}, float32)")
+    B, ecfg, _, reqs = _four_chip_config(model, seed)
 
     one = ServingEngine(model, params, ecfg)
     ref_report, ref_s = serve_stream(one, reqs(), B, seed)
@@ -285,6 +307,39 @@ def _four_chip_parity(seed: int) -> None:
     require(not diff, f"requests {diff} diverge between 1 device and mesh")
     log(f"mesh parity: {len(got)} requests, tokens and statuses "
         f"identical, 1 executable, {serve_s:.1f} s")
+    return eng.params
+
+
+def _four_chip_bf16(model: Model, params, seed: int) -> None:
+    """bf16: one decode step on the mesh against one device, then the
+    meshed serve of the stream."""
+    cfg = model.cfg
+    B, ecfg, geo, reqs = _four_chip_config(model, seed)
+    mesh = make_test_mesh(data=2, model=2)
+    rng = np.random.default_rng(seed)
+    prompts = jnp.asarray(rng.integers(
+        0, cfg.vocab, (B, geo.hbm_pages * geo.page_tokens + 256)),
+        jnp.int32)
+    want, got = (np.asarray(decode_step_logits(model, params, prompts,
+                                               geo, m), np.float32)
+                 for m in (None, mesh))
+    d, scale = float(np.abs(got - want).max()), float(np.abs(want).max())
+    agree = int((got.argmax(-1) == want.argmax(-1)).sum())
+    log(f"bf16 mesh vs 1 device decode step: max |dlogits| {d:.5f} "
+        f"(limit {LOGIT_RTOL * scale:.5f} = {LOGIT_RTOL} x max |logit| "
+        f"{scale:.4f}), argmax agrees on {agree}/{B} lanes")
+    require(d <= LOGIT_RTOL * scale, "bf16 mesh and 1-device logits differ")
+
+    eng = ServingEngine(model, params, ecfg, mesh=mesh)
+    compile_s, n_kernel = compile_serve_chunk(eng, B)
+    log(f"bf16 mesh {dict(mesh.shape)} serve chunk compile: "
+        f"{compile_s:.1f} s, tpu_custom_call count {n_kernel}")
+    require(n_kernel > 0, "the meshed serve chunk holds no Pallas kernel")
+    report, serve_s = serve_stream(eng, reqs(), B, seed)
+    check_stream(eng, reqs(), report, cfg.vocab)
+    log(f"bf16 mesh serve: {len(report)} requests ok, "
+        f"{sum(len(r.output) for r in report)} tokens, 1 executable, "
+        f"{serve_s:.1f} s")
 
 
 def main(argv=None) -> int:

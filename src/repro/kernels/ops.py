@@ -13,11 +13,14 @@ the hot path — it is Python-slow).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_bhsd
@@ -28,13 +31,32 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+#: (mesh, one layer's pool PartitionSpec) while a meshed program traces
+_SHARDED_POOLS = contextvars.ContextVar("sharded_pools", default=None)
+
+
+@contextlib.contextmanager
+def sharded_pools(mesh, pool_spec):
+    """Trace `tier_attention`'s Pallas kernel per shard of `mesh`.
+
+    GSPMD cannot partition a Mosaic kernel, so a meshed program that
+    takes the kernel is traced inside this context. `pool_spec` is the
+    layout of one layer's `[B, P, T, KH, HD]` pool, as the program's
+    owner placed it (`launch.shardings.pool_pspec`); queries and the
+    page lists follow its lane and head axes."""
+    token = _SHARDED_POOLS.set((mesh, pool_spec))
+    try:
+        yield
+    finally:
+        _SHARDED_POOLS.reset(token)
+
+
 def tier_attention(q, k_pool, v_pool, page_list, page_valid,
                    *, use_pallas: Optional[bool] = None):
     """Partial attention over one tier -> (out, m, l, page_lse).
 
     TPU: the Pallas paged kernel (page-table gather in SMEM), run per
-    shard under `shard_map` when traced inside a mesh context (GSPMD
-    cannot partition a Mosaic kernel).
+    shard under `shard_map` inside `sharded_pools`.
     Otherwise: the gather-free dense pool form — page_list from
     `tier_lists` is identity-or-hole, holes already have valid == 0,
     so masking alone is exact, and GSPMD keeps pools page-sharded.
@@ -44,36 +66,23 @@ def tier_attention(q, k_pool, v_pool, page_list, page_valid,
     if not use_pallas:
         return ref.pool_attention_ref(q, k_pool, v_pool, page_valid)
     kernel = functools.partial(paged_attention, interpret=not _on_tpu())
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty:
+    layout = _SHARDED_POOLS.get()
+    if layout is None:
         return kernel(q, k_pool, v_pool, page_list, page_valid)
-    return _shard_kernel(kernel, mesh, q, k_pool, v_pool, page_list,
-                         page_valid)
-
-
-def _shard_kernel(kernel, mesh, q, k_pool, v_pool, page_list, page_valid):
-    """The paged kernel per shard: lanes over the batch axes, kv_heads
-    over `model` — the cache layout `launch.shardings.cache_shardings`
-    picks whenever kv_heads divides the model axis. Each shard's heads
-    are independent, so no collective runs inside."""
-    from jax.sharding import PartitionSpec as P
-    from repro.launch.shardings import batch_axes
-    B, KH = q.shape[0], q.shape[1]
-    m = dict(mesh.shape).get("model", 1)
-    if KH % m:
+    mesh, pool = layout
+    lanes, pages, _, heads, _ = pool
+    if pages is not None:
         raise NotImplementedError(
-            f"the Pallas paged kernel shards kv_heads over 'model'; "
-            f"kv_heads={KH} does not divide model={m} (page-sharded "
-            f"pools need a cross-shard LSE merge)")
-    b = batch_axes(mesh, B)
-    h = "model" if m > 1 else None
-    heads = P(b, h, None, None)
-    pool = P(b, None, None, h, None)
-    lane = P(b, None)
+            "the Pallas paged kernel needs each shard to hold whole "
+            "page ranges; page-sharded pools (kv_heads not divisible by "
+            "the model axis) need a cross-shard LSE merge")
+    qs = P(lanes, heads, None, None)
+    ml = P(lanes, heads, None)
+    lane = P(lanes, None)
     return jax.shard_map(
-        kernel, mesh=mesh, in_specs=(heads, pool, pool, lane, lane),
-        out_specs=(heads, P(b, h, None), P(b, h, None), heads),
-        check_vma=False)(q, k_pool, v_pool, page_list, page_valid)
+        kernel, mesh=mesh, in_specs=(qs, pool, pool, lane, lane),
+        out_specs=(qs, ml, ml, qs), check_vma=False)(
+            q, k_pool, v_pool, page_list, page_valid)
 
 
 def tiered_paged_attention(

@@ -24,11 +24,15 @@ import sys
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.core.sa import SAConfig
 from repro.core.tiers import SPECS, spec_for_device
+from repro.kernels import ops
+from repro.launch import shardings as shd
 from repro.launch.jax_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.model import Model
@@ -88,6 +92,39 @@ def run_stream(model, params, args, mesh, *, trace: bool = False):
     t0 = time.perf_counter()
     report = eng.serve(reqs, num_slots=args.batch_slots, seed=args.seed)
     return eng, report, time.perf_counter() - t0
+
+
+def sharded_decode_step(model, geo, mesh):
+    """`model.decode_step`'s logits, jitted for `mesh` with params and
+    a `geo` cache in the serve rules' placement and the Pallas kernel
+    (where taken) run per shard. Returns the jitted function and its
+    (params, cache, token) shardings."""
+    shards = (shd.param_shardings(model.logical_axes(),
+                                  model.abstract_params(), mesh, "serve"),
+              shd.cache_shardings(geo, mesh),
+              NamedSharding(mesh, P(shd.batch_axes(mesh, geo.batch))))
+    pool = shd.pool_pspec(geo, mesh)
+
+    def step(params, cache, token):
+        with ops.sharded_pools(mesh, pool):
+            return model.decode_step(params, cache, token)[0]
+    return jax.jit(step, in_shardings=shards), shards
+
+
+def decode_step_logits(model, params, prompts, geo, mesh=None):
+    """Logits [B, V] of one greedy decode step after prefilling
+    `prompts` [B, S] into a `geo` cache on one device.
+
+    With `mesh`, only the decode step is partitioned
+    (`sharded_decode_step`). Comparing the two isolates the
+    partitioned step from prefill and from the serve loop."""
+    last, cache = jax.jit(model.prefill, static_argnums=(2,))(
+        params, prompts, geo)
+    token = jnp.argmax(last, -1).astype(jnp.int32)
+    if mesh is None:
+        return jax.jit(model.decode_step)(params, cache, token)[0]
+    step, shards = sharded_decode_step(model, geo, mesh)
+    return step(*jax.device_put((params, cache, token), shards))
 
 
 def check_parity(model, params, args, mesh) -> bool:

@@ -16,7 +16,8 @@ Modes:
           over `data` (and `pod` when multi-pod).
 
 The serve loop's mesh surface lives here too: `cache_shardings` (the
-two-tier paged pools), `policy_state_shardings` (per-lane policy state
+two-tier paged pools, one layer's layout in `pool_pspec`),
+`policy_state_shardings` (per-lane policy state
 threaded through the serve scan), and `serve_shardings` (the bundle of
 per-lane / per-step specs `ServingEngine` pins on its fused serve
 chunk). All rules read only `mesh.axis_names` + `mesh.shape`, so they
@@ -146,19 +147,31 @@ def _kv_shard_axis(geo, mesh: Mesh) -> str:
     return "none"
 
 
+def pool_pspec(geo, mesh: Mesh) -> P:
+    """One layer's KV pool [B, P, T, KH, HD]: lanes over data(/pod),
+    the model axis on kv_heads or pages per `_kv_shard_axis`.
+
+    `cache_shardings` stacks it under the layer dim, and the engine's
+    meshed serve chunk hands it to `ops.sharded_pools`, so the Pallas
+    kernel runs per shard on exactly the layout the pools are placed
+    in."""
+    b_ax = batch_axes(mesh, getattr(geo, "batch", None))
+    ax = _kv_shard_axis(geo, mesh)
+    return P(b_ax, "model" if ax == "pages" else None, None,
+             "model" if ax == "kv_heads" else None, None)
+
+
 def cache_shardings(geo, mesh: Mesh) -> Any:
     """Shardings for a PagedKVCache pytree.
 
-    Pools [L, B, P, T, KH, HD]: batch over data(/pod); model axis on
-    kv_heads or pages per `_kv_shard_axis`. Owner/valid tables follow
-    the pools' pages dim so tier_lists stays fully local.
+    Pools [L, B, P, T, KH, HD]: `pool_pspec` under the layer dim.
+    Owner/valid tables follow the pools' lanes and pages dims so
+    tier_lists stays fully local.
     """
     from repro.kvcache.paged import PagedKVCache
-    b_ax = batch_axes(mesh, getattr(geo, "batch", None))
-    ax = _kv_shard_axis(geo, mesh)
-    kh = "model" if ax == "kv_heads" else None
-    pg = "model" if ax == "pages" else None
-    pool = NamedSharding(mesh, P(None, b_ax, pg, None, kh, None))
+    spec = pool_pspec(geo, mesh)
+    b_ax, pg = spec[0], spec[1]
+    pool = NamedSharding(mesh, P(None, *spec))
     owner = NamedSharding(mesh, P(None, b_ax, pg))
     table = NamedSharding(mesh, P(None, b_ax, None))
     vec = NamedSharding(mesh, P(b_ax))

@@ -87,6 +87,7 @@ import numpy as np
 
 from repro.core.latency_model import StepTraffic, step_latency
 from repro.core.tiers import MemorySystemSpec, TPU_V5E
+from repro.kernels import ops
 from repro.kvcache.migrate import MigrationPlan, apply_migrations
 from repro.kvcache.paged import (
     PagedKVCache, abstract_cache, init_cache,
@@ -868,10 +869,12 @@ class ServingEngine:
                       None)
             donate = (1, 2)
 
+        pool_spec = shd.pool_pspec(geo, mesh)
+
         def meshed_chunk_fn(*args):
-            # the trace sees the mesh, so `ops.tier_attention` can run
-            # the Pallas kernel per shard (GSPMD cannot partition it)
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            # the Pallas kernel runs per shard on the pools' own layout
+            # (GSPMD cannot partition it)
+            with ops.sharded_pools(mesh, pool_spec):
                 return serve_chunk_fn(*args)
 
         self._serve_jit = jax.jit(meshed_chunk_fn, donate_argnums=donate,
@@ -1072,15 +1075,11 @@ class ServingEngine:
         if not requests:
             return ServeReport(completed=[])
         B = num_slots if num_slots is not None else min(len(requests), 4)
-        geo = self.model.cache_geometry(
-            B, cfg.max_context, hbm_fraction=cfg.hbm_fraction)
-        self.geo = geo
+        geo = self._prepare_serve(B, sampling)
         # both tiers are device arrays in every mode: on a TPU the host
         # tier is HBM-resident until ROADMAP queue 1 item 2 lands
         self.state = init_cache(geo)
         self.stats = []
-        self._sampling = sampling or SamplingConfig()
-        self._ensure_step_fns()
         pstate = self._policy.init_state(geo)
         if self._serve_place is not None:
             # mesh placement, once per stream: shard the fresh cache +
@@ -1189,16 +1188,7 @@ class ServingEngine:
                 staged = jax.device_put(staged, self._serve_place["plan"])
 
         stride = max(1, cfg.telemetry_stride)
-        root = jax.random.PRNGKey(seed)
-        # host-side lane state poked by _admit_lane; everything the
-        # device needs is re-uploaded per chunk (small [B]-vectors plus
-        # the [B, max_tokens] prompt buffer)
-        hs = {
-            "root": root,
-            "prompt_buf": np.zeros((B, geo.max_tokens), np.int32),
-            "token": np.zeros((B,), np.int32),
-            "keys": np.array(jax.random.split(root, B)),
-        }
+        hs = self._lane_host_state(geo, seed)
         live: Dict[int, Request] = {}          # lane -> request
 
         def admit():
@@ -1345,32 +1335,18 @@ class ServingEngine:
             for req in live.values():
                 if req.admitted_at is None:
                     req.admitted_at = t0
+            outs = self._serve_jit(*self._chunk_args(
+                self.state, pstate, staged, hs, view, credits, stale_np,
+                caps_np, poison_np))
             if cfg.overlap_migrations:
-                (self.state, pstate, staged, tok_d, act_d, _rem_d,
-                 keys_d, prog_d, credits, emitted, first, failed,
-                 pf_d, stats) = self._serve_jit(
-                    self.params, self.state, pstate, staged,
-                    jnp.asarray(hs["token"]), jnp.asarray(view.active),
-                    jnp.asarray(view.remaining), jnp.asarray(hs["keys"]),
-                    jnp.asarray(view.prefilled),
-                    jnp.asarray(view.prompt_len),
-                    jnp.asarray(hs["prompt_buf"]), credits,
-                    jnp.asarray(stale_np), jnp.asarray(caps_np),
-                    jnp.asarray(poison_np))
+                self.state, pstate, staged, *outs = outs
                 # the chunk consumed the staleness marks; releases /
                 # admissions below repopulate them for the next chunk
                 stale_np = np.zeros((B,), bool)
             else:
-                (self.state, pstate, tok_d, act_d, _rem_d, keys_d,
-                 prog_d, credits, emitted, first, failed, pf_d,
-                 stats) = self._serve_jit(
-                    self.params, self.state, pstate,
-                    jnp.asarray(hs["token"]),
-                    jnp.asarray(view.active), jnp.asarray(view.remaining),
-                    jnp.asarray(hs["keys"]), jnp.asarray(view.prefilled),
-                    jnp.asarray(view.prompt_len),
-                    jnp.asarray(hs["prompt_buf"]), credits,
-                    jnp.asarray(caps_np), jnp.asarray(poison_np))
+                self.state, pstate, *outs = outs
+            (tok_d, act_d, _rem_d, keys_d, prog_d, credits, emitted,
+             first, failed, pf_d, stats) = outs
             emitted = np.asarray(emitted)               # [stride, B]
             first = np.asarray(first)                   # [stride, B]
             pf_tok = np.asarray(pf_d)                   # [stride, B]
@@ -1584,35 +1560,70 @@ class ServingEngine:
                                    link_bw=link_bw)
         return spec, detail
 
+    def _prepare_serve(self, num_slots: int,
+                       sampling: Optional[SamplingConfig]):
+        """Set the cache geometry and sampling of a `num_slots`-lane
+        stream and build its step functions; returns the geometry."""
+        cfg = self.cfg
+        self.geo = self.model.cache_geometry(
+            num_slots, cfg.max_context, hbm_fraction=cfg.hbm_fraction)
+        self._sampling = sampling or SamplingConfig()
+        self._ensure_step_fns()
+        return self.geo
+
+    @staticmethod
+    def _lane_host_state(geo, seed: int) -> Dict:
+        """Host-side lane state poked by `_admit_lane`; everything the
+        device needs is re-uploaded per chunk (small [B]-vectors plus
+        the [B, max_tokens] prompt buffer)."""
+        root = jax.random.PRNGKey(seed)
+        return {
+            "root": root,
+            "prompt_buf": np.zeros((geo.batch, geo.max_tokens), np.int32),
+            "token": np.zeros((geo.batch,), np.int32),
+            "keys": np.array(jax.random.split(root, geo.batch)),
+        }
+
+    def _chunk_args(self, state, pstate, staged, hs: Dict, view,
+                    credits, stale, caps, poison) -> tuple:
+        """One serve-chunk call's arguments, in `_serve_jit`'s order
+        (`staged` and `stale` only in overlap mode)."""
+        lanes = (jnp.asarray(hs["token"]), jnp.asarray(view.active),
+                 jnp.asarray(view.remaining), jnp.asarray(hs["keys"]),
+                 jnp.asarray(view.prefilled), jnp.asarray(view.prompt_len),
+                 jnp.asarray(hs["prompt_buf"]), credits)
+        faults = (jnp.asarray(caps), jnp.asarray(poison))
+        if self.cfg.overlap_migrations:
+            return (self.params, state, pstate, staged, *lanes,
+                    jnp.asarray(stale), *faults)
+        return (self.params, state, pstate, *lanes, *faults)
+
     def serve_chunk_shapes(self, num_slots: int, sharding=None) -> tuple:
-        """The argument shapes of one inline serve-chunk call for
-        `num_slots` lanes (what `serve()` passes `_serve_jit`), after
-        building the step functions for that geometry with greedy
-        sampling. `self._serve_jit.lower(*shapes).compile()` then
+        """The argument shapes of one serve-chunk call for `num_slots`
+        lanes with greedy sampling: prepares the engine as `serve()`
+        does, then builds the first chunk's arguments with `serve()`'s
+        own code. `self._serve_jit.lower(*shapes).compile()` then
         compiles the chunk ahead of a stream, for example for a
         described chip (`sharding` on one of its devices) whose arrays
         cannot exist here. `self.params` may be concrete or shapes."""
         cfg = self.cfg
-        self.geo = self.model.cache_geometry(
-            num_slots, cfg.max_context, hbm_fraction=cfg.hbm_fraction)
-        self._sampling = SamplingConfig()
-        self._ensure_step_fns()
-        geo, B, S = self.geo, num_slots, max(1, cfg.telemetry_stride)
-
-        def like(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
-
-        def vec(shape, dtype=jnp.int32):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-        pstate = jax.eval_shape(lambda: self._policy.init_state(geo))
-        return (jax.tree.map(like, self.params),
-                jax.tree.map(like, abstract_cache(geo)),
-                jax.tree.map(like, pstate),
-                vec((B,)), vec((B,), bool), vec((B,)),      # token/active/rem
-                vec((B, 2), jnp.uint32), vec((B,)), vec((B,)),  # keys/prog/len
-                vec((B, geo.max_tokens)), vec(()),          # prompts/credits
-                vec((S,)), vec((S, B), bool))               # caps/poison
+        geo = self._prepare_serve(num_slots, None)
+        stride = max(1, cfg.telemetry_stride)
+        cap_rows = control.plan_capacity(geo, cfg.migration_budget_frac)
+        view = ContinuousBatcher(num_slots, num_slots * geo.max_pages,
+                                 page_tokens=geo.page_tokens).device_view()
+        faults = FaultPlane()
+        staged = (MigrationPlan.empty(cap_rows)
+                  if cfg.overlap_migrations else None)
+        args = self._chunk_args(
+            abstract_cache(geo),
+            jax.eval_shape(lambda: self._policy.init_state(geo)), staged,
+            self._lane_host_state(geo, 0), view, jnp.zeros((), jnp.int32),
+            np.zeros((num_slots,), bool),
+            faults.commit_caps(0, stride, cap_rows),
+            faults.poison_steps(0, stride, view.rids))
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), args)
 
     def _admit_lane(self, req: Request, hs: Dict) -> None:
         """Bind an admitted request to its cache lane for CHUNKED

@@ -1,6 +1,8 @@
 """Pallas kernel correctness: shape/dtype sweeps vs the pure-jnp oracles
 (interpret mode executes the kernel body on CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,18 +64,24 @@ class TestPagedAttentionKernel:
         assert np.all(np.isfinite(np.asarray(o)))
 
     def test_sharded_kernel_under_mesh_context(self):
-        """`ops.tier_attention` runs the kernel under shard_map when the
-        trace carries a mesh (as the engine's meshed serve jit does):
+        """`ops.tier_attention` runs the kernel under shard_map inside
+        `ops.sharded_pools` (as the engine's meshed serve jit does):
         same results as the oracle."""
         from repro.kernels import ops
         from repro.launch.mesh import make_test_mesh
+        from repro.launch.shardings import pool_pspec
+        from repro.kvcache.paged import CacheGeometry
         mesh = make_test_mesh(data=1, model=1)
+        B, KH, G, HD, P, T = 2, 4, 2, 64, 6, 16
+        geo = CacheGeometry(num_layers=1, batch=B, page_tokens=T,
+                            hbm_pages=P, host_pages=P, kv_heads=KH,
+                            head_dim=HD)
         rng = np.random.default_rng(2)
-        q, kp, vp, pl, pv = _rand_paged(rng, 2, 4, 2, 64, 6, 16, 6,
+        q, kp, vp, pl, pv = _rand_paged(rng, B, KH, G, HD, P, T, P,
                                         jnp.float32)
 
         def f(*args):
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            with ops.sharded_pools(mesh, pool_pspec(geo, mesh)):
                 return ops.tier_attention(*args, use_pallas=True)
 
         got = jax.jit(f)(q, kp, vp, pl, pv)
@@ -81,6 +89,21 @@ class TestPagedAttentionKernel:
         for g, w in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        atol=1e-4)
+
+    def test_sharded_kernel_refuses_page_sharded_pools(self):
+        """Each shard's kernel walks whole page ranges: a pool layout
+        with the model axis on pages is refused at trace time."""
+        from jax.sharding import PartitionSpec
+        from repro.kernels import ops
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh(data=1, model=1)
+        rng = np.random.default_rng(4)
+        args = _rand_paged(rng, 2, 2, 2, 64, 4, 16, 4, jnp.float32)
+        pages = PartitionSpec(None, "model", None, None, None)
+        with ops.sharded_pools(mesh, pages):
+            with pytest.raises(NotImplementedError):
+                jax.jit(functools.partial(
+                    ops.tier_attention, use_pallas=True))(*args)
 
     def test_pool_attention_matches_identity_paged(self):
         """Gather-free SPMD path == paged oracle with identity layout."""

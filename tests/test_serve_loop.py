@@ -75,6 +75,27 @@ class TestServeParity:
 
 
 class TestServeStream:
+    @pytest.mark.parametrize("overlap", [False, True])
+    def test_chunk_shapes_are_the_served_call(self, dense_model, overlap):
+        """`serve_chunk_shapes`, which ahead-of-time compiles read, has
+        the shapes and dtypes of the arguments `serve` passes."""
+        model, params = dense_model
+        rng = np.random.default_rng(4)
+        reqs = [Request(rid=i, prompt=rng.integers(0, model.cfg.vocab,
+                                                    (24,)),
+                        max_new_tokens=3) for i in range(2)]
+        eng = ServingEngine(model, params,
+                            _cfg(overlap_migrations=overlap))
+        served = []
+        build = eng._chunk_args
+        eng._chunk_args = lambda *a: served.append(build(*a)) or served[-1]
+        eng.serve(reqs, num_slots=2)
+        del eng._chunk_args
+
+        def spec(tree):
+            return jax.tree.map(lambda x: (x.shape, x.dtype), tree)
+        assert spec(eng.serve_chunk_shapes(2)) == spec(served[0])
+
     def test_mixed_length_stream_zero_retraces(self, dense_model):
         """More requests than slots, mixed prompt/budget lengths: every
         request completes with its exact budget, the fused chunk

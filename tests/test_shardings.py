@@ -104,6 +104,16 @@ def test_cache_shardings_specs():
     assert cs.hbm_owner.spec == P(None, ("data",), "model")
 
 
+@pytest.mark.parametrize("kv_heads", [2, 3])
+def test_pool_pspec_is_cache_pool_layout_per_layer(kv_heads):
+    """The layout the Pallas kernel is shard_mapped with is the one the
+    cache is placed in, minus the layer dim."""
+    mesh = am(data=2, model=2)
+    geo = geo_stub(kv_heads=kv_heads, batch=4)
+    spec = shd.pool_pspec(geo, mesh)
+    assert P(None, *spec) == shd.cache_shardings(geo, mesh).k_hbm.spec
+
+
 # --------------------------------------------------------------------- #
 # serve-loop bundles
 # --------------------------------------------------------------------- #

@@ -11,11 +11,13 @@ and every worker collects the same tests.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import ops
@@ -92,3 +94,34 @@ def test_serve_chunk_compiles_with_kernel_and_fits(one_chip, internlm2,
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+def _pool_all_gathers(hlo: str, cfg) -> list:
+    """All-gathers whose result ends in a KV pool's [T, heads, HD]
+    (all heads or one model shard's)."""
+    T, KH, HD = cfg.kv_page_tokens, cfg.kv_heads, cfg.head_dim
+    tails = {f",{T},{kh},{HD}]" for kh in (KH, KH // 2)}
+    found = []
+    for line in hlo.splitlines():
+        op = re.search(r"\sall-gather(?:-start)?\(", line)
+        if op and any(t in line[:op.start()] for t in tails):
+            found.append(line.strip())
+    return found
+
+
+def test_meshed_serve_chunk_runs_kernel_per_shard(topo, internlm2,
+                                                  monkeypatch):
+    """The serve chunk on data=2,model=2 over the described 2x2: the
+    kernel is in the program (per shard, under shard_map), and no KV
+    pool is all-gathered to feed it."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    model = Model(internlm2)
+    eng = ServingEngine(model, model.abstract_params(), EngineConfig(
+        max_context=4096, hbm_fraction=0.25, prefill_chunk=64,
+        telemetry_stride=32), mesh=mesh)
+    args = eng.serve_chunk_shapes(8)          # builds `_serve_jit`
+    hlo = eng._serve_jit.lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    assert not _pool_all_gathers(hlo, internlm2)
