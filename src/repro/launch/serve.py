@@ -94,6 +94,42 @@ def run_stream(model, params, args, mesh, *, trace: bool = False):
     return eng, report, time.perf_counter() - t0
 
 
+def boundary_summary(chunks) -> dict:
+    """One summary of a stream's chunks from `ServeReport.chunks`: the
+    host's mean milliseconds per chunk in each phase of `serve()`
+    (set-up as its total), the wall milliseconds per step from
+    dispatch to readback, the shares (in %) of lane-steps that decoded
+    or consumed prompt tokens and of steps in which some lane consumed
+    prompt tokens, prompt tokens per step, admissions and releases per
+    chunk, and the queue's mean and largest depth at dispatch."""
+    if not chunks:
+        return {}
+    n = len(chunks)
+    # in the order the phases first ran
+    phases = [p for p in dict.fromkeys(p for c in chunks
+                                       for p in c.phase_s) if p != "setup"]
+    steps = sum(len(c.stamps) for c in chunks)
+    busy = sum(int((c.decoding + c.prefilling).sum()) for c in chunks)
+    prefill = sum(int((c.prefilling > 0).sum()) for c in chunks)
+    depth = [c.queue_depth for c in chunks]
+    return {
+        "chunks": n,
+        "setup_ms": 1e3 * chunks[0].phase_s.get("setup", 0.0),
+        "host_ms": {p: 1e3 * sum(c.phase_s.get(p, 0.0) for c in chunks)
+                    / n for p in phases},
+        "step_ms": 1e3 * sum(c.t_ready - c.t_dispatch for c in chunks)
+                   / steps,
+        "lane_occupancy": 100.0 * busy / (steps * len(chunks[0].rids)),
+        "prefill_step_share": 100.0 * prefill / steps,
+        "prompt_tokens_per_step":
+            sum(int(c.prompt_tokens.sum()) for c in chunks) / steps,
+        "admitted_per_chunk": sum(c.admitted for c in chunks) / n,
+        "released_per_chunk": sum(c.released for c in chunks) / n,
+        "queue_depth_mean": sum(depth) / n,
+        "queue_depth_max": max(depth),
+    }
+
+
 def sharded_decode_step(model, geo, mesh):
     """`model.decode_step`'s logits, jitted for `mesh` with params and
     a `geo` cache in the serve rules' placement and the Pallas kernel
@@ -232,6 +268,18 @@ def main(argv=None) -> int:
     if report.ttft:
         print(f"ttft p50 {report.ttft['p50'] * 1e3:.1f} ms  "
               f"tpot p50 {report.tpot.get('p50', 0.0) * 1e3:.2f} ms")
+    b = boundary_summary(report.chunks)
+    if b:
+        phases = ", ".join(f"{p} {ms:.2f}" for p, ms in b["host_ms"].items())
+        print(f"host ms per chunk over {b['chunks']} chunks: {phases} "
+              f"(setup {b['setup_ms']:.1f} once)")
+        print(f"step {b['step_ms']:.2f} ms  lane occupancy "
+              f"{b['lane_occupancy']:.1f}%  prefill step share "
+              f"{b['prefill_step_share']:.1f}%  prompt tokens/step "
+              f"{b['prompt_tokens_per_step']:.1f}  per chunk: admitted "
+              f"{b['admitted_per_chunk']:.2f}, released "
+              f"{b['released_per_chunk']:.2f}, queue depth "
+              f"{b['queue_depth_mean']:.2f} (max {b['queue_depth_max']})")
     print(f"modeled tokens/s {s.get('modeled_tokens_per_s', 0.0):.0f}  "
           f"hbm hit rate {s.get('mean_hbm_hit_rate', 0.0):.2f}  "
           f"serve executables {eng._serve_jit._cache_size()}")

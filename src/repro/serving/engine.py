@@ -212,6 +212,88 @@ class StepStats:
     hbm_hit_rate: float
 
 
+#: the host phases of `serve()`, each a profiler span `serve.<phase>`
+PHASES = ("setup", "upload", "dispatch", "readback", "account", "reap",
+          "release", "admit", "wait", "report")
+#: the `jax.named_scope`s of the serve chunk's device work
+SCOPES = ("decode", "lane_merge", "migrate", "prefill", "sample")
+
+
+@dataclasses.dataclass
+class ServeChunk:
+    """One serve chunk as the host saw it (`ServeReport.chunks`). Every
+    count is a sum over arrays the chunk boundary reads back anyway: no
+    extra device output, no extra sync.
+
+    Host `time.time()`: `t_dispatch` as the chunk is dispatched,
+    `t_ready` once its outputs are read back, `stamps` [stride] the
+    time each step is stamped with (a request's `first_token_at` and
+    `finished_at` are entries of it). `phase_s` holds the seconds of
+    each host phase (the `serve.<phase>` spans) since the previous
+    chunk's readback: the boundary before this chunk (`setup` for the
+    first) and the chunk's own dispatch and readback.
+
+    `rids` [B] is each lane's request (-1: free). Per step [stride]:
+    `decoding` counts the lanes that emitted a decode token,
+    `prefilling` the lanes that consumed prompt tokens, and
+    `prompt_tokens` the tokens they consumed. `admitted` counts the
+    requests admitted since the previous chunk, `queue_depth` those
+    queued at dispatch, and `released` the lanes released after it."""
+
+    t_dispatch: float
+    t_ready: float
+    stamps: np.ndarray
+    phase_s: Dict[str, float]
+    rids: np.ndarray
+    decoding: np.ndarray
+    prefilling: np.ndarray
+    prompt_tokens: np.ndarray
+    admitted: int
+    queue_depth: int
+    released: int = 0
+
+
+class _Phases:
+    """The host phases of one `serve()` call. `to(name)` ends the
+    running phase and starts `name` (one of `PHASES`; None ends the
+    last), so the phases tile the call. Each runs under the profiler
+    span `serve.<name>`, on the device trace's clock, and its seconds
+    add to `tally[name]` until `take()` hands them to a chunk record."""
+
+    def __init__(self):
+        self.tally: Dict[str, float] = {}
+        self._name: Optional[str] = None
+        self._span = None
+        self._t = 0.0
+
+    def to(self, name: Optional[str]) -> None:
+        if name == self._name:
+            return
+        if name is not None and name not in PHASES:
+            raise ValueError(f"unknown serve phase {name!r}")
+        now = time.perf_counter()
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self.tally[self._name] = \
+                self.tally.get(self._name, 0.0) + now - self._t
+        self._name, self._t, self._span = name, now, None
+        if name is not None:
+            self._span = jax.profiler.TraceAnnotation("serve." + name)
+            self._span.__enter__()
+
+    def take(self) -> Dict[str, float]:
+        """The seconds by phase since the last `take()`."""
+        out, self.tally = self.tally, {}
+        return out
+
+
+def _scope(name: str):
+    """The serve chunk's `jax.named_scope` `name` (one of `SCOPES`)."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown serve scope {name!r}")
+    return jax.named_scope(name)
+
+
 @dataclasses.dataclass
 class ServeReport:
     """`serve()`'s return value: the completed requests plus
@@ -266,6 +348,8 @@ class ServeReport:
         dataclasses.field(default_factory=dict)
     #: aggregate stream headroom (live vs SA/Belady/static totals)
     headroom: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: the stream's serve chunks in order, as the host saw them
+    chunks: List[ServeChunk] = dataclasses.field(default_factory=list)
 
     @property
     def statuses(self) -> Dict[int, str]:
@@ -277,7 +361,8 @@ class ServeReport:
     def build(completed: List[Request],
               rejected: Optional[List[Request]] = None,
               events: Optional[List[dict]] = None,
-              eos_id: Optional[int] = None) -> "ServeReport":
+              eos_id: Optional[int] = None,
+              chunks: Optional[List[ServeChunk]] = None) -> "ServeReport":
         """Assemble a report from terminal requests: TTFT/TPOT
         mean/p50/p95 from the completed requests' wall-clock stamps,
         the TTFT decomposition percentiles, and EOS-stop counts."""
@@ -317,7 +402,8 @@ class ServeReport:
         return ServeReport(completed=list(completed), ttft=pct(ttfts),
                            tpot=pct(tpots), ttft_parts=parts, eos=eos,
                            rejected=list(rejected or []),
-                           events=list(events or []))
+                           events=list(events or []),
+                           chunks=list(chunks or []))
 
     def __iter__(self):
         return iter(self.completed)
@@ -452,28 +538,31 @@ class ServingEngine:
             # handed to the policy (so access-history policies track
             # the true stream) and to the telemetry capture
             read = mask if mask is not None else cache.page_table >= 0
-            logits, state = model.decode_step(params, state, token,
-                                              **kwargs)
+            with _scope("decode"):
+                logits, state = model.decode_step(params, state, token,
+                                                  **kwargs)
             if active is not None:
                 # per-slot masking: inactive lanes keep their pre-step
                 # cache verbatim (no token write, no length bump)
-                state = _set_cache(state, control.lane_merge(
-                    cache, _get_cache(state), active))
+                with _scope("lane_merge"):
+                    state = _set_cache(state, control.lane_merge(
+                        cache, _get_cache(state), active))
             cache = _get_cache(state)
             # read traffic is counted on post-decode, pre-migration
             # residency (the step's attention read the old placement)
             occ = control.occupancy(cache)
-            plan, pstate, (n_pro, n_dem) = policy.plan(
-                cache, pstate, active, budget, read_mask=read)
-            if mig_cap is not None:
-                # migration-fault channel (serve only): commit at most
-                # `mig_cap` promote rows this step — cap is traced DATA
-                # (NO_FAULT_CAP = identity), so the clean and faulted
-                # streams share one executable. Telemetry counts the
-                # COMMITTED moves, so pricing and the bridge's scores
-                # see the placement that actually happened.
-                plan = throttle_plan(plan, mig_cap)
-                n_pro, n_dem = plan.row_counts()
+            with _scope("migrate"):
+                plan, pstate, (n_pro, n_dem) = policy.plan(
+                    cache, pstate, active, budget, read_mask=read)
+                if mig_cap is not None:
+                    # migration-fault channel (serve only): commit at most
+                    # `mig_cap` promote rows this step — cap is traced DATA
+                    # (NO_FAULT_CAP = identity), so the clean and faulted
+                    # streams share one executable. Telemetry counts the
+                    # COMMITTED moves, so pricing and the bridge's scores
+                    # see the placement that actually happened.
+                    plan = throttle_plan(plan, mig_cap)
+                    n_pro, n_dem = plan.row_counts()
             moves = jnp.stack([n_pro, n_dem]).astype(jnp.int32)
             base = jnp.concatenate([occ, moves])
             if capture:
@@ -485,7 +574,8 @@ class ServingEngine:
                 stats = (base, read, control.page_tiers(cache))
             else:
                 stats = (base,)
-            state = _set_cache(state, apply_migrations(cache, plan))
+            with _scope("migrate"):
+                state = _set_cache(state, apply_migrations(cache, plan))
             return logits, state, pstate, stats
 
         def chunk_fn(params, state, pstate, tokens):
@@ -540,22 +630,25 @@ class ServingEngine:
                 mask = control.quest_page_mask(cache, sparsity)
                 kwargs["logical_page_mask"] = mask
             read = mask if mask is not None else cache.page_table >= 0
-            logits, state = model.decode_step(params, state, token,
-                                              **kwargs)
-            state = _set_cache(state, control.lane_merge(
-                cache, _get_cache(state), active))
+            with _scope("decode"):
+                logits, state = model.decode_step(params, state, token,
+                                                  **kwargs)
+            with _scope("lane_merge"):
+                state = _set_cache(state, control.lane_merge(
+                    cache, _get_cache(state), active))
             cache = _get_cache(state)
             # occupancy + read-time placement are PRE-commit: this
             # step's attention read the old placement
             occ = control.occupancy(cache)
             tiers = control.page_tiers(cache) if capture else None
-            commit = control.revalidate_plan(staged, cache)
-            commit = throttle_plan(commit, mig_cap)
-            n_pro, n_dem = commit.row_counts()
-            cache = apply_migrations(cache, commit)
-            state = _set_cache(state, cache)
-            staged, pstate, _ = policy.plan(cache, pstate, active,
-                                            budget, read_mask=read)
+            with _scope("migrate"):
+                commit = control.revalidate_plan(staged, cache)
+                commit = throttle_plan(commit, mig_cap)
+                n_pro, n_dem = commit.row_counts()
+                cache = apply_migrations(cache, commit)
+                state = _set_cache(state, cache)
+                staged, pstate, _ = policy.plan(cache, pstate, active,
+                                                budget, read_mask=read)
             moves = jnp.stack([n_pro, n_dem]).astype(jnp.int32)
             base = jnp.concatenate([occ, moves])
             stats = (base, read, tiers) if capture else (base,)
@@ -676,8 +769,9 @@ class ServingEngine:
                 logits = jnp.where((dec & poi)[:, None], nanv, logits)
                 bad = dec & ~jnp.isfinite(logits).all(axis=-1)
                 dec_ok = dec & ~bad
-                ks, sub = split_lanes(ks)
-                nxt = sampler(logits, sub)
+                with _scope("sample"):
+                    ks, sub = split_lanes(ks)
+                    nxt = sampler(logits, sub)
                 rem = rem - dec_ok.astype(rem.dtype)
                 fin = dec_ok & (rem <= 0)
                 if eos is not None:
@@ -709,7 +803,8 @@ class ServingEngine:
 
                 def run_pf(args):
                     c, t, s, n = args
-                    return model.prefill_chunk(params, c, t, s, n)
+                    with _scope("prefill"):
+                        return model.prefill_chunk(params, c, t, s, n)
 
                 def skip_pf(args):
                     return (jnp.zeros(pf_logits_sds.shape,
@@ -734,7 +829,8 @@ class ServingEngine:
                 logits1 = jnp.where((pf & poi)[:, None], nanv1, logits1)
                 bad0 = crossed & ~jnp.isfinite(logits1).all(axis=-1)
                 crossed = crossed & ~bad0
-                tok0 = sampler(logits1, sub)
+                with _scope("sample"):
+                    tok0 = sampler(logits1, sub)
                 first = jnp.where(crossed, tok0, -1)
                 tok = jnp.where(crossed, tok0, tok)
                 rem = rem - crossed.astype(rem.dtype)
@@ -785,7 +881,8 @@ class ServingEngine:
                 # deterministic, so a re-admitted request can reproduce
                 # the evicted one's exact (slot, logical) pairs. Mask
                 # them out before the chunk runs.
-                staged = control.mask_plan_lanes(staged, stale)
+                with _scope("migrate"):
+                    staged = control.mask_plan_lanes(staged, stale)
                 return _serve_chunk_impl(
                     params, state, pstate, staged, token, active,
                     remaining, keys, prefilled, prompt_len, prompt_buf,
@@ -965,6 +1062,12 @@ class ServingEngine:
         masked `control.release_lanes` call covering every completion
         in the chunk, and admits queued requests — pure bookkeeping
         (`_admit_lane`): a prompt row, counters, and a sampling key.
+        Every host statement runs in exactly one phase (`_Phases`):
+        the profiler spans `serve.setup`, then per chunk `upload`,
+        `dispatch`, `readback`, `account`, `reap`, `release`, `admit`
+        (and `wait` in an idle open loop), then `report`, which tile
+        the call. `ServeReport.chunks` keeps a `ServeChunk` per chunk:
+        its phase seconds and the lane counts its outputs show.
 
         Sampling (temperature / top-k / top-p) runs inside the fused
         loop with per-slot PRNG keys derived from (`seed`, request id);
@@ -1074,6 +1177,11 @@ class ServingEngine:
                 f"lane insertion")
         if not requests:
             return ServeReport(completed=[])
+        #: the call's host phases; each chunk's record takes their
+        #: seconds since the previous chunk's readback
+        phases = _Phases()
+        phases.to("setup")
+        chunks: List[ServeChunk] = []
         B = num_slots if num_slots is not None else min(len(requests), 4)
         geo = self._prepare_serve(B, sampling)
         # both tiers are device arrays in every mode: on a TPU the host
@@ -1250,7 +1358,9 @@ class ServingEngine:
         admit()
         shed_slo()
         view = batcher.device_view()
+        n_bound = 0                            # admissions chunked so far
         while batcher.has_work or pending:
+            phases.to("admit")
             if submit_arrivals():
                 admit()
                 shed_slo()
@@ -1273,11 +1383,13 @@ class ServingEngine:
                     # idle stream with future arrivals (open loop):
                     # sleep toward the next one, bounded so the
                     # boundary cadence stays responsive
+                    phases.to("wait")
                     wait = pending[0].arrival_s - (time.time() - t_start)
                     if wait > 0:
                         time.sleep(min(wait, 0.05))
                     continue
                 break
+            phases.to("upload")
             step0 = batcher.step_idx
             events.extend(faults.window_events(step0, stride))
             # tier fault: reprice + recalibrate under the spec that
@@ -1335,9 +1447,14 @@ class ServingEngine:
             for req in live.values():
                 if req.admitted_at is None:
                     req.admitted_at = t0
-            outs = self._serve_jit(*self._chunk_args(
+            args = self._chunk_args(
                 self.state, pstate, staged, hs, view, credits, stale_np,
-                caps_np, poison_np))
+                caps_np, poison_np)
+            queue_depth = len(batcher.queue)
+            phases.to("dispatch")
+            t_dispatch = time.time()
+            outs = self._serve_jit(*args)
+            phases.to("readback")
             if cfg.overlap_migrations:
                 self.state, pstate, staged, *outs = outs
                 # the chunk consumed the staleness marks; releases /
@@ -1355,6 +1472,8 @@ class ServingEngine:
             hs["keys"] = np.array(keys_d)               # admit() pokes them
             prog = np.asarray(prog_d)
             done_d = ~np.asarray(act_d)
+            t_ready = time.time()
+            phases.to("account")
             # telemetry: only steps where at least one lane DECODED —
             # prefill-only steps (first tokens included) are charged to
             # the prefill stage, matching the simulator's convention;
@@ -1386,6 +1505,16 @@ class ServingEngine:
 
             def stamp(row):
                 return t0 + (row + 1) / stride * span
+            chunks.append(ServeChunk(
+                t_dispatch=t_dispatch, t_ready=t_ready,
+                stamps=np.array([stamp(s) for s in range(stride)]),
+                phase_s=phases.take(), rids=view.rids.copy(),
+                decoding=(emitted >= 0).sum(axis=1),
+                prefilling=(pf_tok > 0).sum(axis=1),
+                prompt_tokens=pf_tok.sum(axis=1),
+                admitted=len(batcher.bindings) - n_bound,
+                queue_depth=queue_depth))
+            n_bound = len(batcher.bindings)
 
             release = np.zeros((B,), bool)
             for lane, req in list(live.items()):
@@ -1416,6 +1545,7 @@ class ServingEngine:
                 if req.first_token_at is None and first[:, lane].max() >= 0:
                     req.first_token_at = stamp(
                         int(np.argmax(first[:, lane] >= 0)))
+                    req.first_token_read_at = t_ready
                     req.phase = "decoding"
                 req.output.extend(int(rows[s]) for s in got)
                 req.generated += len(got)
@@ -1438,6 +1568,7 @@ class ServingEngine:
                         batcher.complete(req)
                     if got.size:
                         req.finished_at = stamp(int(got[-1]))
+            phases.to("reap")
             # deadline + cooperative cancellation, at chunk-boundary
             # granularity: reaped lanes release pages like any other
             # completion; queued requests are dropped before admission
@@ -1467,6 +1598,8 @@ class ServingEngine:
             # a released lane's staged plan rows are garbage for any
             # successor tenant — stale until the next chunk masks them
             stale_np |= release
+            phases.to("release")
+            chunks[-1].released = int(release.sum())
             if release.any():
                 # ONE masked release per boundary covers every
                 # completion in the chunk — including instant
@@ -1474,6 +1607,7 @@ class ServingEngine:
                 # device call each at admission
                 self.state = self._release_jit(self.state,
                                                jnp.asarray(release))
+            phases.to("admit")
             delta = faults.pool_delta(step0, stride)
             if delta:
                 batcher.resize_pool(delta)
@@ -1484,8 +1618,12 @@ class ServingEngine:
             shed_slo()
             admit()
             view = batcher.device_view()
-        return ServeReport.build(batcher.completed, batcher.rejected,
-                                 events, eos_id=cfg.eos_id)
+        phases.to("report")
+        report = ServeReport.build(batcher.completed, batcher.rejected,
+                                   events, eos_id=cfg.eos_id,
+                                   chunks=chunks)
+        phases.to(None)
+        return report
 
     def _measure_migration_spec(self, geo, *, iters: int = 5):
         """Microbenchmark the jitted migration commit and derive a spec

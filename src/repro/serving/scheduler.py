@@ -108,6 +108,11 @@ class Request:
     submitted_at: float = 0.0
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    #: wall-clock instant the host read back the chunk that delivered
+    #: the first token (`ServeChunk.t_ready`): when a streaming client
+    #: could first see it. `first_token_at` is the step's stamp, spread
+    #: over the chunk, so it can lie later for a chunk's last steps.
+    first_token_read_at: Optional[float] = None
     #: terminal disposition ("pending" while in flight; ends in one of
     #: TERMINAL_STATUSES — see module constant)
     status: str = "pending"
@@ -264,6 +269,7 @@ class ContinuousBatcher:
         req.prefilled = 0
         req.submitted_at = time.time()
         req.first_token_at = None
+        req.first_token_read_at = None
         req.finished_at = None
         req.status = "pending"
         req.error = None
@@ -439,10 +445,3 @@ class ContinuousBatcher:
         """Fraction of batch slots holding a live request."""
         live = sum(0 if s.free else 1 for s in self.slots)
         return live / len(self.slots)
-
-    def page_pressure(self) -> float:
-        """Fraction of the KV page pool currently reserved (1.0 when a
-        shrink fault has emptied the pool entirely)."""
-        if self.total_pages <= 0:
-            return 1.0
-        return 1.0 - self.free_pages / self.total_pages
