@@ -217,6 +217,18 @@ def prefill_cache(geo: CacheGeometry, k: jax.Array, v: jax.Array,
         length=length, importance=cache.importance)
 
 
+def no_write_slot(cache: PagedKVCache) -> int:
+    """NO_WRITE, the write slot of a lane that writes nothing this step.
+
+    One past the last host slot (hbm_pages + host_pages): out of range
+    in both tiers, so `write_token_layer` drops the lane's K/V and
+    `allocate_token_page` its page-table and owner-map entries. The
+    serve step gives it to every lane that is not decoding, which keeps
+    such a lane's pools and tables bitwise as they were without a
+    select over the pools."""
+    return cache.k_hbm.shape[2] + cache.k_host.shape[2]
+
+
 # ---------------------------------------------------------------------------
 # jit-safe cache mutation primitives (operate on ONE layer slice)
 # ---------------------------------------------------------------------------
@@ -226,16 +238,19 @@ def write_token_layer(k_hbm_l, v_hbm_l, k_host_l, v_host_l, slot, offset,
     """Write one token's (k, v) into physical page `slot` at `offset`.
 
     Shapes: pools [B, P, T, KH, HD]; slot/offset [B] int32;
-    k_new/v_new [B, KH, HD]. slot >= hbm_pages addresses the host pool.
+    k_new/v_new [B, KH, HD]. slot >= hbm_pages addresses the host pool;
+    a lane whose slot is `no_write_slot` (NO_WRITE) writes nothing.
     """
     hbm_pages = k_hbm_l.shape[1]
     host_pages = k_host_l.shape[1]
     in_hbm = slot < hbm_pages
-    # masked-out writes use an out-of-range index and mode="drop": one
-    # [B,KH,HD] scatter per pool, no gather+select round-trip of the
-    # full pool (that pattern lowers to full-pool traffic). NOTE: the
-    # sentinel must be OOB-high — negative indices wrap NumPy-style
-    # before the scatter and would hit the last page.
+    # the tier a slot misses gets an out-of-range index and mode="drop":
+    # one [B,KH,HD] scatter per pool, no gather+select round-trip of the
+    # full pool (that pattern lowers to full-pool traffic). NO_WRITE is
+    # out of range in both tiers, so a lane that is not decoding leaves
+    # both pools bitwise as they were. NOTE: a sentinel must be
+    # OOB-high — negative indices wrap NumPy-style before the scatter
+    # and would hit the last page.
     host_slot = jnp.where(~in_hbm, slot - hbm_pages,
                           jnp.int32(host_pages))
     hbm_slot = jnp.where(in_hbm, slot, jnp.int32(hbm_pages))

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.kvcache.paged import (
-    IMPORTANCE_EMA, PagedKVCache, allocate_prompt_pages,
+    IMPORTANCE_EMA, PagedKVCache, allocate_prompt_pages, no_write_slot,
     write_token_layer, write_tokens_layer,
 )
 from repro.models.config import ModelConfig
@@ -217,7 +217,9 @@ def allocate_token_page(cache: PagedKVCache,
                         write_slot: jax.Array) -> PagedKVCache:
     """Register the logical page receiving this step's token in the page
     table / owner maps (MUST run before tier_lists so the fresh page is
-    visible to the attention kernel)."""
+    visible to the attention kernel). A lane whose write slot is NO_WRITE
+    (`paged.no_write_slot`) registers nothing: every index it takes is
+    out of range and dropped."""
     import dataclasses as dc
     L, B = write_slot.shape
     hbm_pages = cache.k_hbm.shape[2]
@@ -225,19 +227,20 @@ def allocate_token_page(cache: PagedKVCache,
     T = cache.k_hbm.shape[3]
     max_pages = cache.page_table.shape[2]
     logical = jnp.minimum(cache.length // T, max_pages - 1)   # [B]
+    logical = jnp.broadcast_to(logical[None, :], (L, B))
     lidx = jnp.arange(L)[:, None]
     bidx = jnp.arange(B)[None, :]
-    page_table = cache.page_table.at[lidx, bidx, logical[None, :]].set(
-        write_slot)
-    in_hbm = write_slot < hbm_pages
-    hslot = jnp.clip(write_slot, 0, hbm_pages - 1)
+    writes = write_slot < no_write_slot(cache)
+    page = jnp.where(writes, logical, max_pages)
+    page_table = cache.page_table.at[lidx, bidx, page].set(
+        write_slot, mode="drop")
+    hslot = jnp.where(write_slot < hbm_pages, write_slot, hbm_pages)
     hbm_owner = cache.hbm_owner.at[lidx, bidx, hslot].set(
-        jnp.where(in_hbm, logical[None, :],
-                  cache.hbm_owner[lidx, bidx, hslot]))
-    eslot = jnp.clip(write_slot - hbm_pages, 0, host_pages - 1)
+        logical, mode="drop")
+    eslot = jnp.where(write_slot >= hbm_pages, write_slot - hbm_pages,
+                      host_pages)                 # NO_WRITE: host_pages
     host_owner = cache.host_owner.at[lidx, bidx, eslot].set(
-        jnp.where(~in_hbm, logical[None, :],
-                  cache.host_owner[lidx, bidx, eslot]))
+        logical, mode="drop")
     return dc.replace(cache, page_table=page_table, hbm_owner=hbm_owner,
                       host_owner=host_owner)
 
@@ -279,9 +282,15 @@ def dense_decode_step(params, cfg: ModelConfig, cache: PagedKVCache,
     hl, hv, el, ev = cache.tier_lists(
         logical_page_mask=logical_page_mask)  # [L,B,P*]
 
+    # the pools ride the carry and each layer's slice is written back in
+    # place: as scan outputs they would be fresh buffers, which the
+    # serve chunk's decode branch then copies whole into its result
     def body(carry, xs):
-        hcur = carry
-        lp, k_hbm_l, v_hbm_l, k_host_l, v_host_l, slot, hl_l, hv_l, el_l, ev_l = xs
+        hcur, pools = carry
+        l, lp, slot, hl_l, hv_l, el_l, ev_l = xs
+        k_hbm_l, v_hbm_l, k_host_l, v_host_l = (
+            jax.lax.dynamic_index_in_dim(p, l, keepdims=False)
+            for p in pools)
         x = rms_norm(hcur, lp["attn_norm"], cfg.norm_eps)
         q, k, v = attn_qkv(x, lp, cfg, pos[:, None])
         # write this token's k/v BEFORE attending (it must see itself)
@@ -302,11 +311,17 @@ def dense_decode_step(params, cfg: ModelConfig, cache: PagedKVCache,
         o = o.reshape(B, 1, cfg.num_heads, cfg.head_dim)
         hcur = hcur + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
         hcur = dense_mlp_block(hcur, lp, cfg)
-        return hcur, (k_hbm_l, v_hbm_l, k_host_l, v_host_l, imp)
+        pools = tuple(
+            jax.lax.dynamic_update_index_in_dim(p, new, l, 0)
+            for p, new in zip(pools, (k_hbm_l, v_hbm_l, k_host_l,
+                                      v_host_l)))
+        return (hcur, pools), imp
 
-    xs = (params["layers"], cache.k_hbm, cache.v_hbm, cache.k_host,
-          cache.v_host, write_slot, hl, hv, el, ev)
-    h, (k_hbm, v_hbm, k_host, v_host, imp) = jax.lax.scan(body, h, xs)
+    pools = (cache.k_hbm, cache.v_hbm, cache.k_host, cache.v_host)
+    xs = (jnp.arange(write_slot.shape[0]), params["layers"], write_slot,
+          hl, hv, el, ev)
+    (h, (k_hbm, v_hbm, k_host, v_host)), imp = jax.lax.scan(
+        body, (h, pools), xs)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, h)[:, 0]
 
@@ -316,9 +331,11 @@ def dense_decode_step(params, cfg: ModelConfig, cache: PagedKVCache,
 
 
 def _bump_valid(valid, slot, offset, T, *, hbm: bool, hbm_pages: int):
-    """Account for the token written this step in the tier valid counts."""
+    """Account for the token written this step in the tier valid counts
+    (none for a NO_WRITE slot, which lies past the host tier)."""
     B = valid.shape[0]
-    in_tier = (slot < hbm_pages) if hbm else (slot >= 0)
+    in_tier = (slot < hbm_pages) if hbm else (
+        (slot >= 0) & (slot < valid.shape[1]))
     s = jnp.clip(slot, 0, valid.shape[1] - 1)
     bidx = jnp.arange(B)
     bumped = valid.at[bidx, s].set(

@@ -51,11 +51,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kvcache.migrate import MigrationPlan
-from repro.kvcache.paged import NO_SLOT, PagedKVCache
+from repro.kvcache.paged import NO_SLOT, PagedKVCache, no_write_slot
 
 
-def choose_write_slot(cache: PagedKVCache) -> jax.Array:
-    """Physical slot [L, B] receiving this step's token."""
+def choose_write_slot(cache: PagedKVCache,
+                      active: Optional[jax.Array] = None) -> jax.Array:
+    """Physical slot [L, B] receiving this step's token. Lanes outside
+    `active` (bool [B]; None = every lane) get NO_WRITE
+    (`paged.no_write_slot`), so the decode step writes nothing there."""
     T = cache.k_hbm.shape[3]
     hbm_pages = cache.k_hbm.shape[2]
     host_pages = cache.k_host.shape[2]
@@ -74,7 +77,10 @@ def choose_write_slot(cache: PagedKVCache) -> jax.Array:
 
     spill = hbm_pages + jnp.where(has_e, first_e, host_pages - 1)
     fresh = jnp.where(has_h, first_h, spill)
-    return jnp.where(existing >= 0, existing, fresh).astype(jnp.int32)
+    slot = jnp.where(existing >= 0, existing, fresh).astype(jnp.int32)
+    if active is None:
+        return slot
+    return jnp.where(active[None, :], slot, no_write_slot(cache))
 
 
 def quest_page_mask(cache: PagedKVCache, sparsity: float) -> jax.Array:
@@ -306,10 +312,12 @@ def lane_modes(active: jax.Array, prefilled: jax.Array,
     from prefill to decode mid-chunk without any host involvement —
     and it gates the whole control plane: the decode plane's write-slot
     choice / Quest masking / sampling apply to decoding lanes (the
-    decode plane still RUNS every lane — `lane_merge` discards the
-    others bitwise), while `plan_migrations(active=decoding)` keeps the
-    migration planner off half-prefilled lanes so chunked prefill lands
-    exactly the Static Placement that `prefill_cache` would (the
+    decode plane still RUNS every lane, but the others take the NO_WRITE
+    write slot, so their token writes nothing to the pools or tables,
+    and `lane_merge` restores their length and importance), while
+    `plan_migrations(active=decoding)` keeps the migration planner off
+    half-prefilled lanes so chunked prefill lands exactly the Static
+    Placement that `prefill_cache` would (the
     bitwise-parity anchor). Half-filled prefill pages stay
     placement-visible throughout: `allocate_prompt_pages` registers
     them in the owner maps, so `occupancy` telemetry and the next
@@ -327,23 +335,19 @@ def _lane_bcast(active: jax.Array, ndim: int, axis: int) -> jax.Array:
 
 def lane_merge(old: PagedKVCache, new: PagedKVCache,
                active: jax.Array) -> PagedKVCache:
-    """Keep `new` for active lanes, `old` for the rest (active bool [B]).
+    """Keep `new` for active lanes, `old` for the rest (active bool [B]),
+    of what a decode step changes in every lane: `length` and the
+    `importance` EMA. Pools, page table and owner maps come from `new`
+    whole: a lane outside `active` wrote nothing there, because its
+    write slot was NO_WRITE (`choose_write_slot(cache, active)`).
 
     With `active` all-True this is a bitwise identity on `new`, which is
     what makes a single-request `serve` reproduce `generate` exactly.
     """
-    def m1(o, n):
-        return jnp.where(_lane_bcast(active, n.ndim, 1), n, o)
-
-    return PagedKVCache(
-        k_hbm=m1(old.k_hbm, new.k_hbm), v_hbm=m1(old.v_hbm, new.v_hbm),
-        k_host=m1(old.k_host, new.k_host),
-        v_host=m1(old.v_host, new.v_host),
-        page_table=m1(old.page_table, new.page_table),
-        hbm_owner=m1(old.hbm_owner, new.hbm_owner),
-        host_owner=m1(old.host_owner, new.host_owner),
-        length=jnp.where(active, new.length, old.length),
-        importance=m1(old.importance, new.importance))
+    return dataclasses.replace(
+        new, length=jnp.where(active, new.length, old.length),
+        importance=jnp.where(_lane_bcast(active, new.importance.ndim, 1),
+                             new.importance, old.importance))
 
 
 def release_lanes(cache: PagedKVCache, lanes: jax.Array) -> PagedKVCache:

@@ -528,7 +528,8 @@ class ServingEngine:
         def step_fn(params, state, pstate, token, active=None,
                     mig_cap=None):
             cache = _get_cache(state)
-            kwargs = {"write_slot": control.choose_write_slot(cache)}
+            kwargs = {"write_slot": control.choose_write_slot(cache,
+                                                              active)}
             mask = None
             if masked:
                 mask = control.quest_page_mask(cache, sparsity)
@@ -543,7 +544,8 @@ class ServingEngine:
                                                   **kwargs)
             if active is not None:
                 # per-slot masking: inactive lanes keep their pre-step
-                # cache verbatim (no token write, no length bump)
+                # cache verbatim (NO_WRITE dropped their token write;
+                # the merge undoes their length bump and importance EMA)
                 with _scope("lane_merge"):
                     state = _set_cache(state, control.lane_merge(
                         cache, _get_cache(state), active))
@@ -624,7 +626,8 @@ class ServingEngine:
                  the new staged carry.
             """
             cache = _get_cache(state)
-            kwargs = {"write_slot": control.choose_write_slot(cache)}
+            kwargs = {"write_slot": control.choose_write_slot(cache,
+                                                              active)}
             mask = None
             if masked:
                 mask = control.quest_page_mask(cache, sparsity)
@@ -716,8 +719,9 @@ class ServingEngine:
 
                 # decode plane: skipped (lax.cond) on pure-prefill
                 # steps — step_fn with dec all-False is a bitwise
-                # no-op on the cache (lane_merge freezes every lane,
-                # the planner plans nothing) and its stats row is
+                # no-op on the cache (every lane writes NO_WRITE and
+                # lane_merge keeps every length and importance, the
+                # planner plans nothing) and its stats row is
                 # filtered at the boundary, so skipping it only saves
                 # the dead forward
                 def run_dec(args):

@@ -381,21 +381,155 @@ class TestLaneOps:
 
     def test_lane_merge_all_active_is_identity(self):
         _, cache = self._cache()
-        bumped = dataclasses.replace(cache, length=cache.length + 1)
+        bumped = dataclasses.replace(cache, length=cache.length + 1,
+                                     importance=cache.importance + 1.0)
         out = control.lane_merge(cache, bumped,
                                  jnp.asarray(np.array([True, True])))
         for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(bumped)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_lane_merge_freezes_inactive(self):
+        """The merge restores an inactive lane's length and importance,
+        and takes pools and tables from the step whole: NO_WRITE already
+        kept the inactive lane's there."""
         _, cache = self._cache()
         bumped = dataclasses.replace(cache, length=cache.length + 1,
-                                     importance=cache.importance + 1.0)
+                                     importance=cache.importance + 1.0,
+                                     k_hbm=cache.k_hbm + 1.0)
         out = control.lane_merge(cache, bumped,
                                  jnp.asarray(np.array([False, True])))
         assert int(out.length[0]) == 16 and int(out.length[1]) == 17
         assert float(out.importance[0, 0, 0]) == 0.0
         assert float(out.importance[0, 1, 0]) == 1.0
+        assert out.k_hbm is bumped.k_hbm
+
+    def test_choose_write_slot_gives_inactive_lanes_no_write(self):
+        from repro.kvcache.paged import no_write_slot
+        geo, cache = self._cache()
+        slot = control.choose_write_slot(
+            cache, jnp.asarray(np.array([False, True])))
+        assert no_write_slot(cache) == geo.hbm_pages + geo.host_pages
+        np.testing.assert_array_equal(np.asarray(slot[:, 0]),
+                                      no_write_slot(cache))
+        np.testing.assert_array_equal(
+            np.asarray(slot[:, 1]),
+            np.asarray(control.choose_write_slot(cache)[:, 1]))
+
+
+def _full_pool_merge(old, new, active):
+    """The reference frozen-lane merge: a select over every leaf."""
+    def m1(o, n):
+        lanes = active if n.ndim == 1 else active.reshape(
+            (1, -1) + (1,) * (n.ndim - 2))
+        return jnp.where(lanes, n, o)
+    return jax.tree.map(m1, old, new)
+
+
+def _mixed_lanes(name):
+    """A cache whose seven lanes are in every state a serve step meets:
+    decoding into an existing HBM page, a fresh HBM page, an existing
+    host page and a fresh host page (HBM full); prefilling; empty; and
+    finished (pages still bound until the chunk boundary)."""
+    cfg = configs.get_smoke(name)
+    m = Model(cfg)
+    params = m.init(jax.random.key(0))
+    geo = m.cache_geometry(7, 64, hbm_fraction=0.5, pad_to=1)
+    assert (geo.hbm_pages, geo.host_pages) == (2, 3)
+    from repro.kvcache.paged import init_cache
+    rng = np.random.default_rng(3)
+    n = np.array([20, 16, 40, 32, 8, 0, 24], np.int32)
+    prompts = jnp.asarray(rng.integers(0, cfg.vocab, (7, 48)), jnp.int32)
+    _, cache = m.prefill_chunk(params, init_cache(geo), prompts,
+                               jnp.zeros((7,), jnp.int32), jnp.asarray(n))
+    active = jnp.asarray(np.array([1, 1, 1, 1, 0, 0, 0], bool))
+    token = jnp.asarray(rng.integers(0, cfg.vocab, (7,)), jnp.int32)
+    return m, params, cache, active, token
+
+
+class TestFrozenLanes:
+    """A lane that is not decoding writes nothing in a serve step: its
+    write slot is NO_WRITE, and `lane_merge` selects no pool."""
+
+    @staticmethod
+    def _step(name):
+        m, params, cache, active, token = _mixed_lanes(name)
+        _, stepped = m.decode_step(
+            params, cache, token,
+            write_slot=control.choose_write_slot(cache, active))
+        got = control.lane_merge(cache, stepped, active)
+        _, ref = m.decode_step(params, cache, token,
+                               write_slot=control.choose_write_slot(cache))
+        want = _full_pool_merge(cache, ref, active)
+        # frozen lanes: pools, tables and owners are bitwise the
+        # pre-step cache already before the merge; importance and
+        # length after it
+        frozen = ~np.asarray(active)
+
+        def kept(c, field):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(c, field))[:, frozen],
+                np.asarray(getattr(cache, field))[:, frozen], field)
+
+        written = ("k_hbm", "v_hbm", "k_host", "v_host", "page_table",
+                   "hbm_owner", "host_owner")
+        for field in written:
+            kept(stepped, field)
+        for field in written + ("importance",):
+            kept(got, field)
+        np.testing.assert_array_equal(np.asarray(got.length),
+                                      np.asarray(cache.length) + ~frozen)
+        # every decoding lane registered its page where the reference
+        # did, and wrote tokens into both tiers
+        for field in ("page_table", "hbm_owner", "host_owner", "length"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                          np.asarray(getattr(want, field)))
+        assert not np.array_equal(np.asarray(got.k_hbm),
+                                  np.asarray(cache.k_hbm))
+        assert not np.array_equal(np.asarray(got.k_host),
+                                  np.asarray(cache.k_host))
+        return got, want
+
+    def test_mixed_lane_step_matches_full_pool_merge(self):
+        got, want = self._step("internlm2-1.8b")
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_moe_mixed_lane_step_freezes_frozen_lanes(self):
+        """MoE routes the whole batch as one group, so frozen lanes
+        still compete for expert capacity and a decoding lane's K/V may
+        differ from the reference's; what a frozen lane keeps may not."""
+        self._step("granite-moe-3b-a800m")
+
+    def test_step_selects_no_pool(self, dense_model):
+        """The jaxpr of the serve step with a lane mask has no select
+        whose result is pool-shaped ([..., T, KH, HD])."""
+        model, params = dense_model
+        cfg = model.cfg
+        eng = ServingEngine(model, params, _cfg())
+        rng = np.random.default_rng(0)
+        eng.start(jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)),
+                              jnp.int32))
+        jaxpr = jax.make_jaxpr(eng._step_jit)(
+            params, eng.state, eng._pstate, jnp.zeros((2,), jnp.int32),
+            jnp.asarray(np.array([True, False])))
+        tail = (cfg.kv_page_tokens, cfg.kv_heads, cfg.head_dim)
+        assert _pool_select_eqns(jaxpr.jaxpr, tail) == []
+
+
+def _pool_select_eqns(jaxpr, tail) -> list:
+    """`select_n` equations, nested jaxprs included, whose result shape
+    ends in `tail`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "select_n" and any(
+                tuple(v.aval.shape[-3:]) == tail for v in eqn.outvars):
+            found.append(eqn)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _pool_select_eqns(sub, tail)
+    return found
 
 
 class TestMaskPlumbing:

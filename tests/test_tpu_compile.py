@@ -76,24 +76,46 @@ def test_paged_kernel_compiles_for_v5e(one_chip, internlm2, tier, pages):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_serve_chunk_compiles_with_kernel_and_fits(one_chip, internlm2,
-                                                    monkeypatch):
-    """The fused serve chunk `chip_smoke.py` runs: the Pallas kernel of
-    both tiers is in the compiled program, and its arguments plus
-    temporaries fit one v5e's HBM."""
-    # the engine asks the default backend (the CPU here) whether to
-    # take the kernel: steer it to the chip's branch for this compile
-    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    model = Model(internlm2)
-    eng = ServingEngine(model, model.abstract_params(), EngineConfig(
-        max_context=4096, hbm_fraction=0.25, prefill_chunk=64,
-        telemetry_stride=32))
-    args = eng.serve_chunk_shapes(8, one_chip)
-    compiled = eng._serve_jit.lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2
-    mem = compiled.memory_analysis()
+@pytest.fixture(scope="module")
+def serve_chunk(one_chip, internlm2):
+    """The fused serve chunk `chip_smoke.py` runs, compiled once."""
+    with pytest.MonkeyPatch.context() as mp:
+        # the engine asks the default backend (the CPU here) whether to
+        # take the kernel: steer it to the chip's branch for this compile
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        model = Model(internlm2)
+        eng = ServingEngine(model, model.abstract_params(), EngineConfig(
+            max_context=4096, hbm_fraction=0.25, prefill_chunk=64,
+            telemetry_stride=32))
+        args = eng.serve_chunk_shapes(8, one_chip)
+        return eng._serve_jit.lower(*args).compile()
+
+
+def test_serve_chunk_compiles_with_kernel_and_fits(serve_chunk):
+    """The Pallas kernel of both tiers is in the compiled program, and
+    its arguments plus temporaries fit one v5e's HBM."""
+    assert serve_chunk.as_text().count("tpu_custom_call") >= 2
+    mem = serve_chunk.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+def _pool_selects(hlo: str, cfg) -> list:
+    """Selects, fused or not, whose result ends in a KV pool's
+    [T, KH, HD]."""
+    tail = f",{cfg.kv_page_tokens},{cfg.kv_heads},{cfg.head_dim}]"
+    found = []
+    for line in hlo.splitlines():
+        op = re.search(r"\sselect\(", line)
+        if op and tail in line[:op.start()]:
+            found.append(line.strip())
+    return found
+
+
+def test_serve_chunk_selects_no_pool(serve_chunk, internlm2):
+    """Lanes that are not decoding drop their token write (NO_WRITE),
+    so no step of the chunk selects between two whole pools."""
+    assert not _pool_selects(serve_chunk.as_text(), internlm2)
 
 
 def _pool_all_gathers(hlo: str, cfg) -> list:
