@@ -1,4 +1,12 @@
-"""granite-8b — llama-arch dense GQA, code model [arXiv:2405.04324; hf]."""
+"""granite-8b — llama-arch dense GQA, code model.
+
+Source: IBM Granite Code 8B, ibm-granite/granite-8b-code-base
+config.json on Hugging Face (arXiv:2405.04324), as the benchmark's
+`bench/configs/granite-8b.json` names it. No copy of that config.json
+is in the repository, so two things are unverified: `rope_theta` (1e6
+here), and the projection biases the checkpoints are believed to set
+(`attention_bias`, `mlp_bias`), which the dense family does not have.
+"""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(

@@ -124,3 +124,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, q_block=q_block,
                                k_block=k_block, interpret=not _on_tpu())
     return out.transpose(0, 2, 1, 3)
+
+
+def pool_layout():
+    """`(mesh, pool_spec)` inside `sharded_pools`, else None."""
+    return _SHARDED_POOLS.get()

@@ -34,7 +34,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels import ops
 from repro.kvcache.paged import NO_SLOT, PagedKVCache
 
 
@@ -205,9 +207,71 @@ def apply_migrations(cache: PagedKVCache,
     Exactly `commit_staged(cache, plan, stage_plan(cache, plan))` — the
     pre-overlap call sites (the inline serve step, `step`/`run`/
     `generate`) keep this entry point, and the two-phase split is
-    bitwise-invisible to them (tests/test_async_migration.py).
+    bitwise-invisible to them (tests/test_async_migration.py). Inside
+    `ops.sharded_pools` (a meshed serve chunk) each shard does the same
+    on its own pages (`_apply_per_shard`); page-sharded pools keep
+    GSPMD's partition.
     """
-    return commit_staged(cache, plan, stage_plan(cache, plan))
+    layout = ops.pool_layout()
+    if layout is None or layout[1][1] is not None:
+        return commit_staged(cache, plan, stage_plan(cache, plan))
+    return _apply_per_shard(cache, plan, *layout)
+
+
+def _apply_per_shard(cache: PagedKVCache, plan: MigrationPlan, mesh,
+                     pool) -> PagedKVCache:
+    """`apply_migrations` on each shard's own lanes and heads, for pools
+    placed by `pool` (one layer's `[B, P, T, KH, HD]` spec, pages whole
+    on every shard).
+
+    A shard moves its pages as `[T * KH, HD]` rows. With a few KV heads
+    a shard (`[T, KH, HD]` pages of 2 heads), the compiler lays the
+    `[T, KH, HD]` window of a page gather or scatter out with `T`
+    second-minor, unlike the decode and prefill steps; the pools were
+    then relaid out whole twice a serve step. The `[T * KH, HD]` view
+    keeps one layout, and is the same bytes in the pools' row-major
+    order."""
+    lanes = pool[0]
+    stacked = P(None, *pool)
+    # the maps follow the pools' lanes (`launch.shardings.cache_shardings`)
+    table = P(None, lanes, None)
+    spec = PagedKVCache(
+        k_hbm=stacked, v_hbm=stacked, k_host=stacked, v_host=stacked,
+        page_table=table, hbm_owner=table, host_owner=table,
+        length=P(lanes), importance=table)
+    axes = lanes if isinstance(lanes, tuple) else \
+        (() if lanes is None else (lanes,))
+
+    def local(c: PagedKVCache, p: MigrationPlan) -> PagedKVCache:
+        B = c.page_table.shape[1]
+        shard = 0
+        for a in axes:
+            shard = shard * jax.lax.axis_size(a) + jax.lax.axis_index(a)
+        lo = shard * B
+
+        def own(layer, batch):
+            mine = (batch >= lo) & (batch < lo + B)
+            return jnp.where(mine, layer, -1), jnp.where(mine, batch - lo, 0)
+
+        pro_layer, pro_batch = own(p.pro_layer, p.pro_batch)
+        dem_layer, dem_batch = own(p.dem_layer, p.dem_batch)
+        p = dataclasses.replace(p, pro_layer=pro_layer, pro_batch=pro_batch,
+                                dem_layer=dem_layer, dem_batch=dem_batch)
+        T, KH, HD = c.k_hbm.shape[3:]
+
+        def reshaped(cache, tail):
+            return dataclasses.replace(cache, **{
+                f: getattr(cache, f).reshape(
+                    *getattr(cache, f).shape[:3], *tail)
+                for f in ("k_hbm", "v_hbm", "k_host", "v_host")})
+
+        rows = reshaped(c, (T * KH, HD))
+        return reshaped(commit_staged(rows, p, stage_plan(rows, p)),
+                        (T, KH, HD))
+
+    plan_spec = jax.tree.map(lambda _: P(), plan)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, plan_spec),
+                         out_specs=spec, check_vma=False)(cache, plan)
 
 
 def migration_bytes(plan: MigrationPlan, page_bytes: int

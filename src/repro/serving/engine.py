@@ -1189,18 +1189,18 @@ class ServingEngine:
         B = num_slots if num_slots is not None else min(len(requests), 4)
         geo = self._prepare_serve(B, sampling)
         # both tiers are device arrays in every mode: on a TPU the host
-        # tier is HBM-resident until ROADMAP queue 1 item 2 lands
-        self.state = init_cache(geo)
+        # tier is HBM-resident until ROADMAP queue 1 item 2 lands; on a
+        # mesh each device makes its own shard (the whole may not fit one)
+        self.state = init_cache(geo) if self._serve_place is None else \
+            jax.jit(init_cache, static_argnums=0,
+                    out_shardings=self._serve_place["cache"])(geo)
         self.stats = []
         pstate = self._policy.init_state(geo)
         if self._serve_place is not None:
-            # mesh placement, once per stream: shard the fresh cache +
-            # policy state (the donated carries) and the params to the
-            # exact shardings the serve jit pins — every later chunk
-            # then reuses the placement (device_put on an
-            # already-matching pytree is a no-op)
-            self.state = jax.device_put(self.state,
-                                        self._serve_place["cache"])
+            # mesh placement, once per stream: the policy state (a
+            # donated carry) and the params to the exact shardings the
+            # serve jit pins — every later chunk then reuses the placement
+            # (device_put on an already-matching pytree is a no-op)
             pstate = jax.device_put(pstate, self._serve_place["pstate"])
             self.params = jax.device_put(self.params,
                                          self._serve_place["params"])

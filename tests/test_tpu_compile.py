@@ -100,10 +100,11 @@ def test_serve_chunk_compiles_with_kernel_and_fits(serve_chunk):
     assert used < V5E_HBM_BYTES, used
 
 
-def _pool_selects(hlo: str, cfg) -> list:
+def _pool_selects(hlo: str, cfg, kv_heads=None) -> list:
     """Selects, fused or not, whose result ends in a KV pool's
-    [T, KH, HD]."""
-    tail = f",{cfg.kv_page_tokens},{cfg.kv_heads},{cfg.head_dim}]"
+    [T, KH, HD] (`kv_heads` a shard's, where the pools are sharded)."""
+    kh = kv_heads or cfg.kv_heads
+    tail = f",{cfg.kv_page_tokens},{kh},{cfg.head_dim}]"
     found = []
     for line in hlo.splitlines():
         op = re.search(r"\sselect\(", line)
@@ -118,11 +119,11 @@ def test_serve_chunk_selects_no_pool(serve_chunk, internlm2):
     assert not _pool_selects(serve_chunk.as_text(), internlm2)
 
 
-def _pool_all_gathers(hlo: str, cfg) -> list:
+def _pool_all_gathers(hlo: str, cfg, model: int = 2) -> list:
     """All-gathers whose result ends in a KV pool's [T, heads, HD]
-    (all heads or one model shard's)."""
+    (all heads or one shard's of a `model`-way axis)."""
     T, KH, HD = cfg.kv_page_tokens, cfg.kv_heads, cfg.head_dim
-    tails = {f",{T},{kh},{HD}]" for kh in (KH, KH // 2)}
+    tails = {f",{T},{kh},{HD}]" for kh in (KH, KH // model)}
     found = []
     for line in hlo.splitlines():
         op = re.search(r"\sall-gather(?:-start)?\(", line)
@@ -147,3 +148,101 @@ def test_meshed_serve_chunk_runs_kernel_per_shard(topo, internlm2,
     hlo = eng._serve_jit.lower(*args).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 2
     assert not _pool_all_gathers(hlo, internlm2)
+
+
+def _computations(hlo: str) -> dict:
+    """{computation name: its instruction lines} of an HLO module."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            name = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)", line).group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line.strip())
+    return comps
+
+
+def _reach(comps: dict, root: str) -> set:
+    """`root` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            for m in re.finditer(r"(?:body|calls|branch_computations|"
+                                 r"to_apply)=\{?([%\w.\-, ]+)\}?", line):
+                todo += [x.strip().lstrip("%") for x in m.group(1).split(",")]
+    return seen
+
+
+def _decode_branch_pool_copies(hlo: str, cfg, kv_heads: int) -> list:
+    """Pool-shaped copies in the serve step's decode `lax.cond` (the one
+    whose branch runs the kernel): in either branch, or in what they
+    call. The carried pools stay in place there, in one layout."""
+    comps = _computations(hlo)
+    tail = f",{cfg.kv_page_tokens},{kv_heads},{cfg.head_dim}]"
+    found, conds = [], 0
+    for lines in comps.values():
+        for line in lines:
+            m = re.search(r"\sconditional\(.*branch_computations=\{([^}]*)\}",
+                          line)
+            if not m:
+                continue
+            branches = [b.strip().lstrip("%") for b in m.group(1).split(",")]
+            reach = [_reach(comps, b) for b in branches]
+            if not any("tpu_custom_call" in line
+                       for r in reach for c in r for line in comps[c]):
+                continue
+            conds += 1
+            for c in set().union(*reach):
+                for line in comps[c]:
+                    op = re.search(r"\scopy(?:-start)?\(", line)
+                    if op and tail in line[:op.start()]:
+                        found.append(line)
+    assert conds, "no decode conditional found"
+    return found
+
+
+@pytest.fixture(scope="module")
+def granite_tp4(topo):
+    """granite-8b's serve chunk at its cell's geometry
+    (`granite-8b.decode-tp4`): 16 lanes, 4096 context, HBM tier 1/8,
+    data=1, model=4 over the described 2x2, compiled once."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)
+        cfg = configs.get("granite-8b")
+        mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)
+        model = Model(cfg)
+        eng = ServingEngine(model, model.abstract_params(), EngineConfig(
+            max_context=4096, hbm_fraction=0.125, prefill_chunk=64,
+            telemetry_stride=32), mesh=mesh)
+        args = eng.serve_chunk_shapes(16)
+        return cfg, eng._serve_jit.lower(*args).compile()
+
+
+def test_granite_tp4_chunk_runs_kernel_per_shard_and_fits(granite_tp4):
+    """The kernel is in the program per shard, no KV pool is
+    all-gathered, and arguments plus temporaries fit a v5e's HBM a
+    chip."""
+    cfg, compiled = granite_tp4
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    assert not _pool_all_gathers(hlo, cfg, model=4)
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, used
+
+
+def test_granite_tp4_pools_stay_in_place(granite_tp4):
+    """With 2 KV heads a shard, no step selects between whole pools, and
+    the decode `lax.cond` copies no pool: the migration moves pages in
+    the pools' own layout (`migrate._apply_per_shard`), so the pools
+    are not relaid out around it."""
+    cfg, compiled = granite_tp4
+    hlo = compiled.as_text()
+    kh = cfg.kv_heads // 4
+    assert not _pool_selects(hlo, cfg, kv_heads=kh)
+    assert not _decode_branch_pool_copies(hlo, cfg, kh)
