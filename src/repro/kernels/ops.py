@@ -55,8 +55,9 @@ def tier_attention(q, k_pool, v_pool, page_list, page_valid,
                    *, use_pallas: Optional[bool] = None):
     """Partial attention over one tier -> (out, m, l, page_lse).
 
-    TPU: the Pallas paged kernel (page-table gather in SMEM), run per
-    shard under `shard_map` inside `sharded_pools`.
+    TPU: the Pallas paged kernel, which walks only the list's live
+    entries (compact list and per-lane count in SMEM), run per shard
+    under `shard_map` inside `sharded_pools`.
     Otherwise: the gather-free dense pool form — page_list from
     `tier_lists` is identity-or-hole, holes already have valid == 0,
     so masking alone is exact, and GSPMD keeps pools page-sharded.

@@ -104,9 +104,10 @@ class PagedKVCache:
     def tier_lists(self, layer=None, logical_page_mask=None):
         """Kernel operands: per-tier (page_list, page_valid).
 
-        page_list[b, s] = s if slot s is occupied else -1 (the kernel
-        streams every pool slot; free slots are masked). page_valid is
-        the number of cached tokens that fall inside the owning page.
+        page_list[b, s] = s if slot s is occupied else -1, so occupied
+        slots keep their pool order; the kernel's wrapper compacts the
+        list and the kernel walks only the occupied entries. page_valid
+        is the number of cached tokens that fall inside the owning page.
         Returns arrays for one layer ([B, P]) or all ([L, B, P]).
 
         logical_page_mask (bool [L, B, max_pages] or [B, max_pages]):

@@ -282,6 +282,7 @@ def main(argv=None) -> int:
               f"{b['queue_depth_mean']:.2f} (max {b['queue_depth_max']})")
     print(f"modeled tokens/s {s.get('modeled_tokens_per_s', 0.0):.0f}  "
           f"hbm hit rate {s.get('mean_hbm_hit_rate', 0.0):.2f}  "
+          f"kernel slot share {s['kernel_slot_share']:.3f}  "
           f"serve executables {eng._serve_jit._cache_size()}")
     return 0
 

@@ -202,7 +202,9 @@ class StepStats:
     """One decode step's modeled cost under the paper's Eq. (1)-(5):
     the latency and the byte volumes (HBM / host reads, migrations in /
     out) the engine's device telemetry priced for that step, plus the
-    step's HBM hit rate (fraction of read bytes served from HBM)."""
+    step's HBM hit rate (fraction of read bytes served from HBM) and
+    its resident pages (pool slots holding a page, both tiers, summed
+    over layers and lanes)."""
 
     modeled_latency_s: float
     h_read: float
@@ -210,6 +212,7 @@ class StepStats:
     m_in: float
     m_out: float
     hbm_hit_rate: float
+    resident_pages: int
 
 
 #: the host phases of `serve()`, each a profiler span `serve.<phase>`
@@ -1822,14 +1825,26 @@ class ServingEngine:
                 modeled_latency_s=lat,
                 h_read=traffic["h_read"], e_read=traffic["e_read"],
                 m_in=traffic["m_in"], m_out=traffic["m_out"],
-                hbm_hit_rate=traffic["h_read"] / denom if denom else 1.0))
+                hbm_hit_rate=traffic["h_read"] / denom if denom else 1.0,
+                resident_pages=int(h_pages) + int(e_pages)))
 
     def summary(self) -> Dict[str, float]:
         """Aggregate the recorded StepStats: step count, modeled total
-        seconds and tokens/s, mean HBM hit rate, migrated bytes."""
+        seconds and tokens/s, mean HBM hit rate, migrated bytes, and
+        `kernel_slot_share`.
+
+        `kernel_slot_share` is the share of the two tiers' pool slots
+        that hold a page, Σ resident pages / (steps × L × B × (Ph + Pe)):
+        the entries the paged kernel walks in a decode step. Its DMAs
+        round each lane's walk up to whole blocks, at most
+        `pages_per_block` − 1 more entries a lane, layer and tier; pages
+        a Quest mask skips are counted here but not walked. It reads 0
+        on an engine that ran no step."""
         if not self.stats:
-            return {}
+            return {"kernel_slot_share": 0.0}
         lat = np.array([s.modeled_latency_s for s in self.stats])
+        geo = self.geo
+        slots = geo.num_layers * geo.batch * geo.max_pages
         return {
             "steps": len(self.stats),
             "modeled_total_s": float(lat.sum()),
@@ -1838,4 +1853,6 @@ class ServingEngine:
                 [s.hbm_hit_rate for s in self.stats])),
             "migrated_bytes": float(sum(s.m_in + s.m_out
                                         for s in self.stats)),
+            "kernel_slot_share": sum(s.resident_pages for s in self.stats)
+            / (len(self.stats) * slots),
         }

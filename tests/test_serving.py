@@ -137,6 +137,46 @@ class TestFusedParity:
         assert sum(s.m_in + s.m_out for s in eng.stats) > 0
 
 
+class TestKernelSlotShare:
+    def test_idle_engine_reads_zero(self, dense_model):
+        eng = ServingEngine(*dense_model, EngineConfig(
+            max_context=128, hbm_fraction=0.25, spec=GH200))
+        assert eng.summary()["kernel_slot_share"] == 0.0
+
+    def test_counts_each_steps_resident_slots(self, dense_model):
+        """On a short spilling stream the counter is Σ over steps of the
+        owner maps' occupied slots, over steps x L x B x (Ph + Pe); the
+        kernel's whole-block walk exceeds it by less than one block a
+        lane, layer and tier."""
+        from repro.kernels.paged_attention import pages_per_block
+        model, params = dense_model
+        eng = ServingEngine(model, params, EngineConfig(
+            max_context=512, hbm_fraction=0.25, policy="importance",
+            attention_sparsity=0.0, spec=GH200, promote_thresh=1e-4))
+        rng = np.random.default_rng(3)
+        eng.start(jnp.asarray(rng.integers(0, model.cfg.vocab, (2, 272)),
+                              jnp.int32))
+        geo = eng.geo
+        T, KH, HD = geo.page_tokens, geo.kv_heads, geo.head_dim
+        size = jnp.dtype(geo.dtype).itemsize
+        tok = jnp.array([1, 2], jnp.int32)
+        occupied = walked = slack = 0
+        for _ in range(6):
+            tok = jnp.argmax(eng.step(tok), -1).astype(jnp.int32)
+            for owner in (eng._cache.hbm_owner, eng._cache.host_owner):
+                n = np.asarray((owner >= 0).sum(-1))          # [L, B]
+                K = pages_per_block(T, KH, HD, size, owner.shape[-1])
+                occupied += int(n.sum())
+                walked += int((-(-n // K) * K).sum())
+                slack += (K - 1) * n.size
+        assert int(np.asarray((eng._cache.host_owner >= 0).sum())) > 0
+        slots = 6 * geo.num_layers * geo.batch * geo.max_pages
+        share = eng.summary()["kernel_slot_share"]
+        assert share == occupied / slots
+        assert 0.0 < share < 1.0
+        assert occupied <= walked <= occupied + slack
+
+
 class TestDevicePlanner:
     def test_promotes_hottest_host_page_into_coldest_slot(self):
         from repro.kvcache.paged import CacheGeometry, prefill_cache

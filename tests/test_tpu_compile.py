@@ -21,7 +21,9 @@ from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import ops
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (BLOCK_BYTES, BLOCK_VMEM_BYTES,
+                                           _vmem_page_bytes, paged_attention,
+                                           pages_per_block)
 from repro.models.model import Model
 from repro.serving.engine import EngineConfig, ServingEngine
 
@@ -57,23 +59,56 @@ def internlm2():
     return configs.get("internlm2-1.8b")
 
 
-@pytest.mark.parametrize("tier,pages", [("hbm", 64), ("host", 208)])
-def test_paged_kernel_compiles_for_v5e(one_chip, internlm2, tier, pages):
-    """The two pools of `serve()` at B=8, max_context 4096,
-    hbm_fraction 0.25: 64 HBM and 208 host pages per lane."""
-    cfg = internlm2
-    B, T = 8, cfg.kv_page_tokens
+#: (tier, pages a lane): `serve()` at B=8, max_context 4096 — hbm_fraction
+#: 0.25 (64/208), the internlm2 cells' tiers (decode-spill 32/240, chat
+#: 192/80), and the tp4 cell's per-shard tiers ("tp4-": 16 lanes, 2 KV
+#: heads, 32/240)
+KERNEL_TIERS = [("hbm", 64), ("host", 208), ("hbm", 32), ("host", 240),
+                ("hbm", 192), ("host", 80), ("tp4-hbm", 32),
+                ("tp4-host", 240)]
+
+
+def _kernel_args(one_chip, tier, pages):
+    """The kernel's operand shapes for one tier of a cell's lanes:
+    internlm2-1.8b on one chip, or a granite-8b shard of the tp4 cell."""
+    if tier.startswith("tp4"):
+        cfg = configs.get("granite-8b")
+        B, KH = 16, cfg.kv_heads // 4
+    else:
+        cfg = configs.get("internlm2-1.8b")
+        B, KH = 8, cfg.kv_heads
+    T, HD = cfg.kv_page_tokens, cfg.head_dim
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((B, pages, T, cfg.kv_heads, cfg.head_dim), jnp.bfloat16)
-    args = (sds((B, cfg.kv_heads, cfg.q_per_kv, cfg.head_dim),
-                jnp.bfloat16), pool, pool,
+    pool = sds((B, pages, T, KH, HD), jnp.bfloat16)
+    return (sds((B, KH, cfg.q_per_kv, HD), jnp.bfloat16), pool, pool,
             sds((B, pages), jnp.int32), sds((B, pages), jnp.int32))
+
+
+@pytest.mark.parametrize("tier,pages", KERNEL_TIERS)
+def test_paged_kernel_compiles_for_v5e(one_chip, tier, pages):
+    """The kernel compiles for a v5e at each tier the cells run."""
+    args = _kernel_args(one_chip, tier, pages)
     kernel = functools.partial(paged_attention, interpret=False)
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tier,pages", KERNEL_TIERS)
+def test_paged_block_rule_fits_vmem(one_chip, tier, pages):
+    """The block-size rule picks, from the page's bytes and the tier's
+    slots, a power-of-two block of at most the tier's slots whose
+    double-buffered K and V blocks stay in their VMEM budget (Mosaic
+    takes the kernel's scratch at that block for a v5e in
+    `test_paged_kernel_compiles_for_v5e`)."""
+    args = _kernel_args(one_chip, tier, pages)
+    T, KH, HD = args[1].shape[2:]
+    K = pages_per_block(T, KH, HD, 2, pages)
+    assert 1 <= K <= pages and K & (K - 1) == 0
+    assert T * KH * HD * 2 * K <= max(BLOCK_BYTES, T * KH * HD * 2)
+    assert 4 * K * _vmem_page_bytes(T, KH, HD, 2) <= BLOCK_VMEM_BYTES
 
 
 @pytest.fixture(scope="module")
