@@ -9,14 +9,12 @@ per figure and are documented in each module).
   fig5  — HBM hit rates at 60% sparsity
   bound — SA upper bound headline (max speedup, W/R convergence,
           accepted-move attribution) + beyond-paper policies + TPU tiers
-  engine— live two-tier serving engine (real paged cache) under the
-          same Eq.(1)-(5) accounting
-  perf  — wall-clock decode steps/s: fused (lax.scan) vs eager vs the
-          pre-fusion host-loop baseline; writes BENCH_engine.json
 
-Roofline numbers come from the dry-run (python -m repro.launch.dryrun,
-then python -m repro.launch.roofline), not from this harness — they are
-compile-time artifacts, not wall-time measurements.
+These are the paper's simulator figures, priced by the Eq.(1)-(5)
+model on the CPU. The serving engine's speed on the chip is measured by
+`bench/run.py`, whose per-cell `paged_attn_roofline`, `mfu` and `mbu`
+are the chip's roofline numbers (`python -m repro.launch.dryrun`
+compiles for forced CPU devices and measures no chip).
 """
 
 from __future__ import annotations
@@ -27,14 +25,12 @@ import sys
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     from benchmarks import (fig3_sparsity, fig4_variation, fig5_hitrate,
-                            live_engine, perf_engine, upper_bound)
+                            upper_bound)
     suites = {
         "fig3": fig3_sparsity.run,
         "fig4": fig4_variation.run,
         "fig5": fig5_hitrate.run,
         "bound": upper_bound.run,
-        "engine": live_engine.run,
-        "perf": perf_engine.run,
     }
     if which != "all":
         suites[which]()

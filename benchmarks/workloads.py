@@ -31,7 +31,7 @@ objects with `arrival_s` stamped for the engine's open-loop driver:
 `ServingEngine.serve` submits each one at the first chunk boundary
 whose wall clock passes its offset. The arrival pattern is pure data —
 all three processes drive ONE serve executable (zero retraces), which
-the bench CI gate pins (`perf_engine --goodput-sweep --ci`).
+tests/test_workloads.py pins.
 """
 
 from __future__ import annotations

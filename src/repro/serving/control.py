@@ -8,7 +8,7 @@ selection, Quest-style top-k page masking, importance-EMA migration
 planning) fuses into one jitted program and can run under `lax.scan`
 (see `ServingEngine.run` / `.generate` and EXPERIMENTS.md §Fused-engine).
 
-Semantics match the original host-side planner exactly:
+Semantics, as a per-(layer, batch) loop would state them:
 
   * write slot: the token's logical page keeps its existing mapping;
     a fresh page takes the first free HBM slot, else the first free
@@ -366,32 +366,6 @@ def release_lanes(cache: PagedKVCache, lanes: jax.Array) -> PagedKVCache:
         host_owner=clr(cache.host_owner, NO_SLOT),
         length=jnp.where(lanes, 0, cache.length),
         importance=clr(cache.importance, 0.0))
-
-
-def insert_lane(cache: PagedKVCache, lane_cache: PagedKVCache,
-                lane: jax.Array) -> PagedKVCache:
-    """Bind a prefilled batch-1 cache to lane `lane` (int32 scalar) of
-    the batched cache. One compile for all lanes: the lane index is
-    data, not shape. No longer on the serve admission path (chunked
-    prefill writes pages in place — PR 3); kept for the
-    eager-admission baseline in benchmarks/perf_engine.py and as the
-    building block for future recurrent/hybrid-state lane insertion."""
-    B = cache.length.shape[0]
-    onehot = jnp.arange(B) == lane
-
-    def ins1(dst, src):
-        return jnp.where(_lane_bcast(onehot, dst.ndim, 1), src, dst)
-
-    return PagedKVCache(
-        k_hbm=ins1(cache.k_hbm, lane_cache.k_hbm),
-        v_hbm=ins1(cache.v_hbm, lane_cache.v_hbm),
-        k_host=ins1(cache.k_host, lane_cache.k_host),
-        v_host=ins1(cache.v_host, lane_cache.v_host),
-        page_table=ins1(cache.page_table, lane_cache.page_table),
-        hbm_owner=ins1(cache.hbm_owner, lane_cache.hbm_owner),
-        host_owner=ins1(cache.host_owner, lane_cache.host_owner),
-        length=jnp.where(onehot, lane_cache.length[0], cache.length),
-        importance=ins1(cache.importance, lane_cache.importance))
 
 
 def page_tiers(cache: PagedKVCache) -> jax.Array:

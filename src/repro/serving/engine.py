@@ -63,9 +63,7 @@ staged at step N-1 concurrently with decode compute, and plans for
 step N+1 off this step's read set — a double-buffered plan/commit
 split with one-step-ahead KV prefetch (EXPERIMENTS.md
 §Async-migration). Decode semantics are placement-invariant, so the
-pipeline changes WHEN pages move, never what attention computes;
-`EngineConfig.measured_payback` additionally recalibrates cost_aware's
-payback bars from a measured migration microbenchmark.
+pipeline changes WHEN pages move, never what attention computes.
 
 Scaling out: `ServingEngine(model, params, cfg, mesh=...)` runs the
 identical serve loop across a jax device mesh — cache pools, migration
@@ -184,17 +182,6 @@ class EngineConfig:
     #: the per-tier LSE merge when interim placements differ.
     #: Serve-path only: step/run/generate always run inline.
     overlap_migrations: bool = False
-    #: calibrate cost_aware's payback thresholds from MEASURED per-page
-    #: migration latency instead of the modeled spec: a one-shot
-    #: microbenchmark at serve start times the jitted commit
-    #: (full-capacity plan vs empty plan) and inverts Eq. (3)'s move
-    #: cost into an effective link bandwidth. Telemetry PRICING stays
-    #: on `spec` (the model is the model); only the policy's
-    #: promote/demote bars move, and tier-fault degradations compose
-    #: onto the measured spec for recalibration. Stamps a
-    #: "payback_measured" event; falls back to the modeled spec when
-    #: the measurement can't resolve the link term.
-    measured_payback: bool = False
 
 
 @dataclasses.dataclass
@@ -384,9 +371,8 @@ class ServeReport:
                  for r in completed
                  if r.first_token_at is not None
                  and r.finished_at is not None and len(r.output) > 1]
-        # decomposition percentiles over requests the chunked loop
-        # attributed (the eager-admission baseline stamps first tokens
-        # at admission, before any chunk runs — no decomposition there)
+        # decomposition percentiles over requests stamped both at
+        # admission and at their first token
         attributed = [r for r in completed
                       if r.first_token_at is not None
                       and r.admitted_at is not None]
@@ -1144,7 +1130,8 @@ class ServingEngine:
         arrival pattern is pure DATA: bursty, diurnal, and Poisson
         streams all drive the same serve-chunk executable (an idle
         stream with pending arrivals sleeps between boundaries; shapes
-        never change). `queue_wait_s` then measures real queueing.
+        never change; tests/test_workloads.py). `queue_wait_s` then
+        measures real queueing.
 
         `slo` layers SLO-aware admission on top of the
         `prefill_budget` token bucket: at every chunk boundary, AFTER
@@ -1157,7 +1144,7 @@ class ServingEngine:
         runs first and removes it from the queue. Per-request TTFT
         decomposition (`queue_wait_s` + `prefill_s` + `throttle_s` ==
         TTFT, exact at the chunk-stride stamp resolution) lands on
-        every chunk-admitted request; `ServeReport.ttft_parts` carries
+        every admitted request; `ServeReport.ttft_parts` carries
         the percentiles and `slo.score_goodput` turns a report + an
         `SLOPolicy` into the goodput row.
 
@@ -1269,26 +1256,7 @@ class ServingEngine:
         base_spec = cfg.spec
         cap_rows = control.plan_capacity(geo, cfg.migration_budget_frac)
         events: List[dict] = []
-        # measured-payback recalibration (cfg.measured_payback): replace
-        # the spec's MODELED link bandwidth with one derived from a
-        # one-shot microbenchmark of the actual jitted migration commit
-        # on this host, and re-derive cost_aware's payback bars from it.
-        # Pricing (StepStats -> Eq.(1)-(5)) stays on the modeled
-        # base_spec — the paper's accounting is the comparable surface;
-        # only the policy's decision thresholds go empirical. Tier
-        # faults compose onto whichever spec governs each consumer.
-        calib_base = base_spec
-        if cfg.measured_payback:
-            measured, detail = self._measure_migration_spec(geo)
-            if measured is not None:
-                calib_base = measured
-                pstate = self._policy.recalibrate(pstate, measured)
-                if self._serve_place is not None:
-                    pstate = jax.device_put(pstate,
-                                            self._serve_place["pstate"])
-            events.append({"kind": "payback_measured", "step": 0,
-                           **detail})
-        last_thresh = calib_base
+        last_spec = base_spec
         fallback = False
         drop_streak = 0
         # overlap mode: the staged-plan scan carry starts as an all
@@ -1307,9 +1275,7 @@ class ServingEngine:
         live: Dict[int, Request] = {}          # lane -> request
 
         def admit():
-            """Admit until no progress (an admission the eager-baseline
-            subclass completes instantly frees its slot for the next
-            queued request within the same boundary)."""
+            """Admit queued requests until the batcher admits none."""
             while True:
                 admitted = batcher.admit()
                 if not admitted:
@@ -1403,13 +1369,8 @@ class ServingEngine:
             # governs this chunk; past the ratio threshold, migrating
             # toward the host tier can't pay back — fall back to static
             spec_now = faults.spec_at(step0, base_spec)
-            # thresholds recalibrate from `calib_base` (== base_spec
-            # unless measured_payback substituted a measured link) with
-            # the same tier-fault scales composed on top; PRICING stays
-            # on spec_now so telemetry remains paper-comparable
-            thresh_now = faults.spec_at(step0, calib_base)
-            if thresh_now != last_thresh:
-                pstate = self._policy.recalibrate(pstate, thresh_now)
+            if spec_now != last_spec:
+                pstate = self._policy.recalibrate(pstate, spec_now)
                 if self._serve_place is not None:
                     # recalibrated values are fresh host scalars —
                     # restore the pinned placement so the chunk jit's
@@ -1417,10 +1378,10 @@ class ServingEngine:
                     # survives the boundary
                     pstate = jax.device_put(pstate,
                                             self._serve_place["pstate"])
-                last_thresh = thresh_now
+                last_spec = spec_now
                 events.append({
                     "kind": "payback_recalibration", "step": step0,
-                    "bw_ratio": thresh_now.bw_ratio})
+                    "bw_ratio": spec_now.bw_ratio})
             if not fallback and spec_now.bw_ratio >= \
                     cfg.fallback_tier_ratio * base_spec.bw_ratio:
                 fallback = True
@@ -1632,79 +1593,6 @@ class ServingEngine:
         phases.to(None)
         return report
 
-    def _measure_migration_spec(self, geo, *, iters: int = 5):
-        """Microbenchmark the jitted migration commit and derive a spec
-        whose link bandwidth is MEASURED rather than modeled.
-
-        Times `apply_migrations` on a synthetic full-capacity swap plan
-        (every row a promote+demote pair, so each row moves one page
-        across the link in each direction) against the all-sentinel
-        empty plan over the same cache — the delta isolates the
-        per-page move cost from fixed dispatch overhead. The latency
-        model prices a move at `1/link_bw + 1/hbm_bw` seconds per byte
-        (repro.core.placement.cost_aware), so the measured
-        seconds-per-byte inverts to a link bandwidth; the returned spec
-        is `cfg.spec` with `link_bw` replaced and the name suffixed
-        "+measured". Only cost_aware's payback thresholds consume this
-        — Eq.(1)-(5) telemetry pricing stays on the modeled spec.
-
-        Runs on the default device even under a mesh (the commit is a
-        per-shard local scatter; a single-device measurement is the
-        per-shard cost). Returns `(spec_or_None, detail)`: None when
-        the measurement cannot be inverted — timer noise drives the
-        delta non-positive, or the implied per-byte cost lands under
-        the modeled HBM floor — and the caller stays fully modeled.
-        `detail` is the `payback_measured` event payload either way.
-        """
-        base = self.cfg.spec
-        cap = control.plan_capacity(geo, self.cfg.migration_budget_frac)
-        L, B = geo.num_layers, geo.batch
-        r = np.arange(cap, dtype=np.int32)
-        pro_src = r % geo.host_pages
-        pro_dst = r % geo.hbm_pages
-        lay = jnp.asarray(r % L)
-        bat = jnp.asarray((r // L) % B)
-        plan = MigrationPlan(
-            lay, bat, jnp.asarray(pro_src), jnp.asarray(pro_dst),
-            jnp.asarray(r % geo.max_pages),
-            lay, bat, jnp.asarray(pro_dst), jnp.asarray(pro_src),
-            jnp.asarray((r + 1) % geo.max_pages))
-        empty = MigrationPlan.empty(cap)
-        cache = init_cache(geo)
-        # jit a LOCAL wrapper, not `apply_migrations` itself: jax's
-        # tracing cache keys on the wrapped function object, so jitting
-        # the module-level function here would leave this measurement's
-        # entry behind in every later `jax.jit(apply_migrations)`
-        fn = jax.jit(lambda c, p: apply_migrations(c, p))
-        # compile + warm both variants outside the timed region
-        jax.block_until_ready(fn(cache, plan))
-        jax.block_until_ready(fn(cache, empty))
-
-        def best(p):
-            t = float("inf")
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(cache, p))
-                t = min(t, time.perf_counter() - t0)
-            return t
-
-        delta = best(plan) - best(empty)
-        moved = 2 * cap * geo.page_bytes()
-        detail = {"rows": int(cap), "bytes": int(moved),
-                  "delta_s": float(delta),
-                  "modeled_link_bw": float(base.link_bw),
-                  "measured_link_bw": None}
-        if delta <= 0.0 or moved == 0:
-            return None, detail
-        inv_link = delta / moved - 1.0 / base.hbm_bw
-        if inv_link <= 0.0:
-            return None, detail
-        link_bw = 1.0 / inv_link
-        detail["measured_link_bw"] = float(link_bw)
-        spec = dataclasses.replace(base, name=base.name + "+measured",
-                                   link_bw=link_bw)
-        return spec, detail
-
     def _prepare_serve(self, num_slots: int,
                        sampling: Optional[SamplingConfig]):
         """Set the cache geometry and sampling of a `num_slots`-lane
@@ -1776,9 +1664,7 @@ class ServingEngine:
         counters (via `req.prefilled`, exported by `device_view`), and
         the lane's sampling key. No device compute, no model forward,
         no per-prompt-length compiles; the prompt starts flowing into
-        the lane's pages at the next chunk's mixed steps. (The
-        eager-admission baseline in benchmarks/perf_engine.py overrides
-        this with the PR 2 whole-prompt forward + `insert_lane`.)"""
+        the lane's pages at the next chunk's mixed steps."""
         lane = req.lane
         prompt = np.asarray(req.prompt).astype(np.int32).ravel()
         hs["prompt_buf"][lane, :] = 0

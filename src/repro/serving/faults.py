@@ -9,7 +9,7 @@ at every chunk boundary and folded into the fused chunk as *data,
 never shape* — the serve executable with the fault channel compiled in
 is the SAME executable whether or not any fault fires (the
 one-executable and zero-retrace pins hold with injection active,
-asserted by tests/test_chaos.py and `perf_engine.py --ci`).
+asserted by tests/test_chaos.py).
 
 Fault taxonomy (all windows/steps are fused serve-step indices, i.e.
 `ContinuousBatcher.step_idx` units):

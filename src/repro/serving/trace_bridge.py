@@ -514,9 +514,8 @@ def goodput_curve(rec: ServeTraceRecord, spec, report, policy, *,
     target scale (`repro.serving.slo.score_goodput`). The curve pairs
     with the aggregate `bound_fraction`: a policy can only convert
     placement headroom into goodput at the scales where latency — not
-    admission — is the binding constraint, which is exactly what the
-    per-policy curves in `BENCH_engine.json["rows"]["goodput"]` show
-    (see `benchmarks/perf_engine.py --goodput-sweep`).
+    admission — is the binding constraint (tests/test_trace_bridge.py
+    compares importance's curve with static's on one contract).
     """
     from repro.serving.slo import score_goodput
 

@@ -233,3 +233,28 @@ def test_serve_stream_one_executable_per_policy(dense_model, name):
     assert all(len(r.output) == r.max_new_tokens for r in report)
     assert eng._serve_jit._cache_size() == 1
     assert eng.batcher.free_pages == eng.batcher.total_pages
+
+
+@pytest.mark.parametrize("name", policy_names())
+def test_generate_stream_one_executable_per_policy(dense_model, name):
+    """Every registered policy drives a multi-chunk fused `generate`
+    stream, with trace capture on, on ONE `_gen_jit` executable: a
+    second stream from a fresh `start` reuses it. The prompt spills
+    past the HBM pool and Quest sparsity concentrates the read set, so
+    every policy but static moves pages."""
+    model, params = dense_model
+    eng = ServingEngine(model, params, EngineConfig(
+        max_context=512, hbm_fraction=0.25, policy=name,
+        attention_sparsity=0.5, spec=GH200, promote_thresh=1e-4,
+        telemetry_stride=4, trace_telemetry=True))
+    rng = np.random.default_rng(0)
+    prompts = jnp.asarray(rng.integers(0, model.cfg.vocab, (1, 272)),
+                          jnp.int32)
+    for _ in range(2):
+        eng.start(prompts)
+        assert int(np.asarray((eng._cache.host_owner >= 0).sum())) > 0
+        out = eng.generate(jnp.array([1], jnp.int32), 12)
+        assert out.shape == (12, 1)
+        moved = sum(s.m_in + s.m_out for s in eng.stats)
+        assert (moved > 0) == (name != "static")
+    assert eng._gen_jit._cache_size() == 1

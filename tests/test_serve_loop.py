@@ -362,23 +362,6 @@ class TestLaneOps:
         assert int(np.asarray((out.hbm_owner[:, 1] >= 0).sum())) == 2
         assert int(out.length[1]) == 16
 
-    def test_insert_lane_binds_batch1_cache(self):
-        geo, cache = self._cache()
-        empty = control.release_lanes(
-            cache, jnp.asarray(np.array([True, True])))
-        geo1 = dataclasses.replace(geo, batch=1)
-        from repro.kvcache.paged import prefill_cache
-        rng = np.random.default_rng(1)
-        kv1 = jnp.asarray(rng.standard_normal((1, 1, 8, 2, 8)), jnp.float32)
-        lane_cache = prefill_cache(geo1, kv1, kv1, 8)
-        out = control.insert_lane(empty, lane_cache, jnp.int32(1))
-        assert int(out.length[1]) == 8 and int(out.length[0]) == 0
-        np.testing.assert_array_equal(np.asarray(out.page_table[:, 1]),
-                                      np.asarray(lane_cache.page_table[:, 0]))
-        np.testing.assert_array_equal(np.asarray(out.k_hbm[:, 1]),
-                                      np.asarray(lane_cache.k_hbm[:, 0]))
-        assert int(np.asarray((out.hbm_owner[:, 0] >= 0).sum())) == 0
-
     def test_lane_merge_all_active_is_identity(self):
         _, cache = self._cache()
         bumped = dataclasses.replace(cache, length=cache.length + 1,

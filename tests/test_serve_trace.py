@@ -13,7 +13,9 @@ Pins the tentpole invariants:
   * telemetry on/off leaves serve outputs and StepStats identical, and
     capture adds ZERO retraces (one serve-chunk executable either way);
   * per-request and aggregate bound fractions are sane (<= 1 + tol)
-    under a mixed continuous-batching stream with real HBM pressure.
+    under a mixed continuous-batching stream with real HBM pressure;
+  * every registered policy serves with capture on in ONE executable,
+    and the bridge scores each of its requests.
 """
 
 import jax
@@ -28,6 +30,7 @@ from repro.core.tiers import GH200
 from repro.models.model import Model
 from repro.serving import trace_bridge
 from repro.serving.engine import EngineConfig, ServingEngine
+from repro.serving.policies import policy_names
 from repro.serving.scheduler import Request
 
 SA_CFG = SAConfig(max_evaluations=8, iters_per_level=3, seed=0)
@@ -255,3 +258,24 @@ class TestMixedStreamScoring:
             out["aggregate"]["bound_fraction"]
         for sc in report.request_scores.values():
             assert {"hit_fraction", "bound_fraction"} <= set(sc)
+
+
+@pytest.mark.parametrize("name", policy_names())
+def test_captured_serve_scored_one_executable_per_policy(dense_model,
+                                                         name):
+    """Every registered policy serves a mixed stream with capture on
+    in ONE serve-chunk executable, and `score_serve` returns the
+    aggregate and a hit and bound fraction for every request."""
+    model, params = dense_model
+    eng = ServingEngine(model, params, _cfg(policy=name, sparsity=0.5,
+                                            trace_telemetry=True))
+    report = eng.serve(_mixed_requests(model, np.random.default_rng(4),
+                                       n=3), num_slots=2, seed=0)
+    assert eng._serve_jit._cache_size() == 1
+    out = trace_bridge.score_serve(trace_bridge.collect_serve(eng), GH200,
+                                   sa_cfg=SA_CFG, report=report)
+    assert out["aggregate"]["live_total_s"] > 0
+    assert "bound_fraction" in out["aggregate"]
+    assert set(out["requests"]) == {0, 1, 2}
+    for sc in out["requests"].values():
+        assert {"hit_fraction", "bound_fraction"} <= set(sc)

@@ -6,7 +6,9 @@ arrival times, lengths, tiers, prompt tokens, and sampling keys. Plus
 the distributional invariants: arrivals sorted and strictly positive,
 lengths >= 1 and page-snapped when asked, tier names from the spec's
 mix, and the truncated-Zipf tail sampler within KS tolerance of the
-exact law it inverts (the CDF is exposed for exactly this test).
+exact law it inverts (the CDF is exposed for exactly this test). And
+the serve loop's side of the contract: every arrival process drives
+ONE serve executable.
 
 Property tests are hypothesis-optional (tests/_hypothesis_compat);
 deterministic smoke companions keep the coverage alive without it.
@@ -15,13 +17,20 @@ deterministic smoke companions keep the coverage alive without it.
 import os
 import sys
 
+import jax
 import numpy as np
+import pytest
 
 from _hypothesis_compat import given, settings, st
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                 os.pardir, "benchmarks"))
 import workloads as wl                                   # noqa: E402
+from repro import configs                                # noqa: E402
+from repro.core.tiers import GH200                       # noqa: E402
+from repro.models.model import Model                     # noqa: E402
+from repro.serving.engine import EngineConfig, ServingEngine  # noqa: E402
+from repro.serving.scheduler import TERMINAL_STATUSES    # noqa: E402
 
 
 def _spec(**kw):
@@ -211,3 +220,39 @@ def test_property_zipf_support(seed, alpha):
     rng = np.random.default_rng(seed)
     draws = wl.sample_zipf(rng, alpha, 128, 500)
     assert draws.min() >= 1 and draws.max() <= 128
+
+
+# --------------------------------------------------------------------------- #
+# arrival processes are data: one serve executable for all of them
+# --------------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def dense_model():
+    m = Model(configs.get_smoke("internlm2-1.8b"))
+    return m, m.init(jax.random.key(0))
+
+
+@pytest.mark.parametrize("arrival", wl.ARRIVALS)
+def test_arrival_process_one_serve_executable(dense_model, arrival):
+    """A closed-loop stream and then an open-loop stream of `arrival`,
+    sampled and stopping on EOS, through ONE engine: every request ends
+    in a terminal status and the serve chunk compiles once. The arrival
+    clock is compressed so the open-loop stream waits well under a
+    second."""
+    model, params = dense_model
+    eng = ServingEngine(model, params, EngineConfig(
+        max_context=128, hbm_fraction=0.25, policy="importance",
+        attention_sparsity=0.5, spec=GH200, promote_thresh=1e-4,
+        telemetry_stride=4, prefill_chunk=16, prefill_budget=24,
+        eos_id=model.cfg.eos_id))
+    base = dict(n_requests=3, rate_rps=8.0, min_prompt=16, max_prompt=64,
+                max_new=6, vocab=model.cfg.vocab, temperature=0.7)
+    closed = wl.generate(wl.WorkloadSpec(seed=3, **base))
+    stream = wl.generate(wl.WorkloadSpec(seed=11, arrival=arrival, **base))
+    scale = min(1.0, 0.25 / float(stream.arrival_s[-1]))
+    for reqs, wload in ((closed.requests(open_loop=False), closed),
+                        (stream.requests(time_scale=scale), stream)):
+        rep = eng.serve(reqs, num_slots=2, **wload.serve_kwargs())
+        assert set(rep.statuses) == {r.rid for r in reqs}
+        assert all(s in TERMINAL_STATUSES for s in rep.statuses.values())
+    assert eng._serve_jit._cache_size() == 1
