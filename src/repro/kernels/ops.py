@@ -25,6 +25,8 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_prefill import paged_prefill_attention
+from repro.models.layers import prefix_chunk_attention, repeat_kv
 
 
 def _on_tpu() -> bool:
@@ -51,6 +53,23 @@ def sharded_pools(mesh, pool_spec):
         _SHARDED_POOLS.reset(token)
 
 
+def _pool_shard_specs():
+    """(mesh, pool spec, its lanes and heads axes) inside
+    `sharded_pools`, else None. The kernels walk whole page ranges, so a
+    pool sharded on pages is refused."""
+    layout = _SHARDED_POOLS.get()
+    if layout is None:
+        return None
+    mesh, pool = layout
+    lanes, pages, _, heads, _ = pool
+    if pages is not None:
+        raise NotImplementedError(
+            "the Pallas paged kernels need each shard to hold whole "
+            "page ranges; page-sharded pools (kv_heads not divisible by "
+            "the model axis) need a cross-shard LSE merge")
+    return mesh, pool, lanes, heads
+
+
 def tier_attention(q, k_pool, v_pool, page_list, page_valid,
                    *, use_pallas: Optional[bool] = None):
     """Partial attention over one tier -> (out, m, l, page_lse).
@@ -67,16 +86,10 @@ def tier_attention(q, k_pool, v_pool, page_list, page_valid,
     if not use_pallas:
         return ref.pool_attention_ref(q, k_pool, v_pool, page_valid)
     kernel = functools.partial(paged_attention, interpret=not _on_tpu())
-    layout = _SHARDED_POOLS.get()
-    if layout is None:
+    shards = _pool_shard_specs()
+    if shards is None:
         return kernel(q, k_pool, v_pool, page_list, page_valid)
-    mesh, pool = layout
-    lanes, pages, _, heads, _ = pool
-    if pages is not None:
-        raise NotImplementedError(
-            "the Pallas paged kernel needs each shard to hold whole "
-            "page ranges; page-sharded pools (kv_heads not divisible by "
-            "the model axis) need a cross-shard LSE merge")
+    mesh, pool, lanes, heads = shards
     qs = P(lanes, heads, None, None)
     ml = P(lanes, heads, None)
     lane = P(lanes, None)
@@ -84,6 +97,50 @@ def tier_attention(q, k_pool, v_pool, page_list, page_valid,
         kernel, mesh=mesh, in_specs=(qs, pool, pool, lane, lane),
         out_specs=(qs, ml, ml, qs), check_vma=False)(
             q, k_pool, v_pool, page_list, page_valid)
+
+
+def prefill_attention(q, pools, pos, valid, *,
+                      use_pallas: Optional[bool] = None):
+    """Chunked-prefill attention of a prompt slice against one layer's
+    two-tier pools -> [B, C, H, HD].
+
+    q: [B, C, H, HD]; pools: (k_hbm, v_hbm, k_host, v_host), the slice's
+    K/V already written at static placement; pos/valid: [B, C] absolute
+    positions (start[b] + j) and the slice's real tokens (a prefix).
+    TPU: the Pallas kernel, which walks only the pages each prefilling
+    lane holds up to its slice's last position (zeros for padding rows
+    and lanes with no prompt tokens), run per shard under `shard_map`
+    inside `sharded_pools`.
+    Otherwise: both tiers concatenated in slot order (logical order for
+    a prefilling lane), KV heads repeated, `prefix_chunk_attention`.
+    """
+    if use_pallas is None:
+        use_pallas = _on_tpu()
+    kh, vh, ke, ve = pools
+    if not use_pallas:
+        B = q.shape[0]
+        T, KH, HD = kh.shape[2:]
+        keys = jnp.concatenate([kh, ke], axis=1)    # [B, Ph+Pe, T, KH, HD]
+        vals = jnp.concatenate([vh, ve], axis=1)
+        S = keys.shape[1] * T
+        keys = keys.reshape(B, S, KH, HD)
+        vals = vals.reshape(B, S, KH, HD)
+        G = q.shape[2] // KH
+        return prefix_chunk_attention(q, repeat_kv(keys, G),
+                                      repeat_kv(vals, G), pos)
+    start = pos[:, 0].astype(jnp.int32)
+    n_valid = valid.sum(axis=1, dtype=jnp.int32)
+    kernel = functools.partial(paged_prefill_attention,
+                               interpret=not _on_tpu())
+    shards = _pool_shard_specs()
+    if shards is None:
+        return kernel(q, kh, vh, ke, ve, start, n_valid)
+    mesh, pool, lanes, heads = shards
+    qs = P(lanes, None, heads, None)
+    lane = P(lanes)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(qs, pool, pool, pool, pool, lane, lane),
+        out_specs=qs, check_vma=False)(q, kh, vh, ke, ve, start, n_valid)
 
 
 def tiered_paged_attention(

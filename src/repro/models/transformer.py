@@ -25,7 +25,7 @@ from repro.kvcache.paged import (
 from repro.models.config import ModelConfig
 from repro.models.layers import (
     apply_rope, attention, constrain_batch, layer_norm,
-    prefix_chunk_attention, repeat_kv, rms_norm, swiglu,
+    repeat_kv, rms_norm, swiglu,
 )
 from repro.models.params import Param
 
@@ -381,26 +381,19 @@ def prefill_chunk_attn(hcur, lp, cfg: ModelConfig, pools, pos, page,
     (k_hbm_l, v_hbm_l, k_host_l, v_host_l); pos/page/offset/valid:
     [B, C] absolute positions and their page coordinates. Writes the
     slice's K/V at static-placement slots (slot == logical page), then
-    attends causally against the pools flattened in slot order — which
-    IS logical token order while the lane is prefilling, because the
-    migration planner only touches lanes that have started decoding.
-    Shared by the dense and moe chunked-prefill forwards.
+    attends causally against the pools (`ops.prefill_attention`) in
+    slot order — which IS logical token order while the lane is
+    prefilling, because the migration planner only touches lanes that
+    have started decoding. Shared by the dense and moe chunked-prefill
+    forwards.
     """
     kh, vh, ke, ve = pools
-    B, C = pos.shape
-    T = kh.shape[2]
     hcur = constrain_batch(hcur)
     x = rms_norm(hcur, lp["attn_norm"], cfg.norm_eps)
     q, k, v = attn_qkv(x, lp, cfg, pos)
     kh, vh, ke, ve = write_tokens_layer(kh, vh, ke, ve, page, offset,
                                         k, v, valid)
-    keys = jnp.concatenate([kh, ke], axis=1)        # [B, Ph+Pe, T, KH, HD]
-    vals = jnp.concatenate([vh, ve], axis=1)
-    S = keys.shape[1] * T
-    keys = keys.reshape(B, S, cfg.kv_heads, cfg.head_dim)
-    vals = vals.reshape(B, S, cfg.kv_heads, cfg.head_dim)
-    o = prefix_chunk_attention(q, repeat_kv(keys, cfg.q_per_kv),
-                               repeat_kv(vals, cfg.q_per_kv), pos)
+    o = ops.prefill_attention(q, (kh, vh, ke, ve), pos, valid)
     hcur = hcur + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
     return hcur, (kh, vh, ke, ve)
 
@@ -433,10 +426,12 @@ def dense_prefill_chunk(params, cfg: ModelConfig, cache: PagedKVCache,
     the first output token can be sampled on device at the step where
     prefill crosses prompt_len.
 
-    Bitwise invariant (pinned by tests/test_chunked_prefill.py): for
-    the valid positions this reproduces `dense_forward` exactly, at ANY
-    chunk budget — per-position ops are shape-invariant and
-    `prefix_chunk_attention` sees the identical visible prefix.
+    Bitwise invariant off the TPU (pinned by
+    tests/test_chunked_prefill.py): for the valid positions this
+    reproduces `dense_forward` exactly, at ANY chunk budget —
+    per-position ops are shape-invariant and `prefix_chunk_attention`
+    sees the identical visible prefix. On the TPU the paged prefill
+    kernel's online softmax matches it to bf16 rounding.
     """
     C = tokens.shape[1]
     T = cache.k_hbm.shape[3]

@@ -77,7 +77,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -404,6 +404,21 @@ class ServeReport:
         return self.completed[i]
 
 
+def prefill_walk(pf_tok: np.ndarray, prog: np.ndarray,
+                 page_tokens: int) -> Tuple[int, int]:
+    """(pages, steps) of a chunk's prefill attention: Σ over steps and
+    lanes of the pages a lane's slice attends to,
+    ceil((start + n) / page_tokens) for a lane consuming n > 0 prompt
+    tokens from `start`, and the steps where any lane consumed one.
+
+    pf_tok: [stride, B] prompt tokens each lane consumed each step;
+    prog: [B] each lane's prompt progress after the chunk, so a step's
+    start is prog less the tokens of that step and the later ones."""
+    start = prog[None, :] - np.cumsum(pf_tok[::-1], axis=0)[::-1]
+    pages = np.where(pf_tok > 0, -(-(start + pf_tok) // page_tokens), 0)
+    return int(pages.sum()), int((pf_tok > 0).any(axis=1).sum())
+
+
 def _get_cache(state) -> PagedKVCache:
     return state if isinstance(state, PagedKVCache) else state["kv"]
 
@@ -453,6 +468,8 @@ class ServingEngine:
         #: serializable config value.
         self.mesh = mesh
         self.stats: List[StepStats] = []
+        #: [pages, steps] of `serve`'s prefill attention (`prefill_walk`)
+        self._prefill_walk = np.zeros(2, np.int64)
         self._sampling = SamplingConfig()
         #: raw (stats, access, tier) chunks when cfg.trace_telemetry
         #: (consumed by repro.serving.trace_bridge.collect)
@@ -1185,6 +1202,7 @@ class ServingEngine:
             jax.jit(init_cache, static_argnums=0,
                     out_shardings=self._serve_place["cache"])(geo)
         self.stats = []
+        self._prefill_walk[:] = 0
         pstate = self._policy.init_state(geo)
         if self._serve_place is not None:
             # mesh placement, once per stream: the policy state (a
@@ -1442,6 +1460,8 @@ class ServingEngine:
             done_d = ~np.asarray(act_d)
             t_ready = time.time()
             phases.to("account")
+            self._prefill_walk += prefill_walk(pf_tok, prog,
+                                               geo.page_tokens)
             # telemetry: only steps where at least one lane DECODED —
             # prefill-only steps (first tokens included) are charged to
             # the prefill stage, matching the simulator's convention;
@@ -1716,8 +1736,8 @@ class ServingEngine:
 
     def summary(self) -> Dict[str, float]:
         """Aggregate the recorded StepStats: step count, modeled total
-        seconds and tokens/s, mean HBM hit rate, migrated bytes, and
-        `kernel_slot_share`.
+        seconds and tokens/s, mean HBM hit rate, migrated bytes,
+        `kernel_slot_share` and `prefill_page_share`.
 
         `kernel_slot_share` is the share of the two tiers' pool slots
         that hold a page, Σ resident pages / (steps × L × B × (Ph + Pe)):
@@ -1725,9 +1745,21 @@ class ServingEngine:
         round each lane's walk up to whole blocks, at most
         `pages_per_block` − 1 more entries a lane, layer and tier; pages
         a Quest mask skips are counted here but not walked. It reads 0
-        on an engine that ran no step."""
+        on an engine that ran no step.
+
+        `prefill_page_share` is the share of the pools that `serve`'s
+        prefill attention reads: Σ pages each prefilling lane's slice
+        attends to / (prefill steps × B × (Ph + Pe)), per layer
+        (`prefill_walk`). The paged prefill kernel walks those pages,
+        rounded up to whole blocks of `pages_per_block`; the
+        concatenated-pool path off the TPU reads every slot, a share of
+        1.0 whatever this counter reads. It reads 0 where no prefill
+        step ran."""
+        pages, steps = self._prefill_walk
+        prefill = {"prefill_page_share": 0.0 if not steps else float(
+            pages) / (steps * self.geo.batch * self.geo.max_pages)}
         if not self.stats:
-            return {"kernel_slot_share": 0.0}
+            return {"kernel_slot_share": 0.0, **prefill}
         lat = np.array([s.modeled_latency_s for s in self.stats])
         geo = self.geo
         slots = geo.num_layers * geo.batch * geo.max_pages
@@ -1741,4 +1773,5 @@ class ServingEngine:
                                         for s in self.stats)),
             "kernel_slot_share": sum(s.resident_pages for s in self.stats)
             / (len(self.stats) * slots),
+            **prefill,
         }

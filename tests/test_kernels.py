@@ -2,6 +2,9 @@
 (interpret mode executes the kernel body on CPU)."""
 
 import functools
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -272,6 +275,105 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(lse1, lse2, atol=1e-4)
 
 
+#: (id, B, KH, G, C, T, Ph, Pe, starts, n_valid, mesh): prompt slices
+#: of C tokens at lane offsets `starts` with `n_valid` real tokens.
+#: tier-straddle: lane 0 crosses page 2 -> 3, the HBM/host boundary,
+#: lane 1 starts at position 0, lane 2 does not prefill; partial-last:
+#: slices shorter than C, one ending mid-page; many-blocks: walks of
+#: several DMA blocks, one to the pool's last page; tp4-shard: granite's
+#: per-chip heads (KH 2, G 4); sharded: under `ops.sharded_pools` on a
+#: data=2, model=2 mesh of forced host devices (2 KV heads a shard)
+PREFILL_CASES = [
+    ("tier-straddle", 3, 8, 2, 16, 16, 3, 5, [40, 0, 20], [16, 16, 0],
+     None),
+    ("partial-last", 3, 2, 4, 16, 16, 2, 6, [33, 100, 0], [5, 12, 0], None),
+    ("many-blocks", 3, 8, 2, 16, 16, 4, 12, [192, 240, 64], [16, 16, 7],
+     None),
+    ("tp4-shard", 2, 2, 4, 16, 16, 2, 14, [0, 200], [16, 9], None),
+    ("sharded", 4, 4, 2, 16, 16, 3, 5, [40, 0, 7, 64], [16, 16, 0, 3],
+     "2,2"),
+]
+
+
+def _prefill_check(case, dtype) -> None:
+    """The paged prefill kernel (`ops.prefill_attention`, interpret
+    mode) against the concatenated-pool path, on pools holding junk
+    past each lane's prefix where the oracle's pools hold zeros: real
+    rows match, padding rows and lanes with no prompt token are 0."""
+    from repro.kernels import ops
+    _, B, KH, G, C, T, Ph, Pe, starts, n_valid, mesh = case
+    HD = 128
+    rng = np.random.default_rng(sum(map(ord, case[0])))
+    q = jnp.asarray(rng.standard_normal((B, C, KH * G, HD)), dtype)
+    start = np.asarray(starts, np.int32)
+    end = start + np.asarray(n_valid, np.int32)
+    clean, junk = [], []
+    for first, P in ((0, Ph), (0, Ph), (Ph, Pe), (Ph, Pe)):
+        x = rng.standard_normal((B, P, T, KH, HD))
+        pos = (first + np.arange(P))[:, None] * T + np.arange(T)[None]
+        past = (pos[None] >= end[:, None, None])[..., None, None]
+        clean.append(jnp.asarray(np.where(past, 0.0, x), dtype))
+        junk.append(jnp.asarray(np.where(past, 8.0 * x + 4.0, x), dtype))
+    pos = jnp.asarray(start[:, None] + np.arange(C)[None], jnp.int32)
+    valid = np.arange(C)[None] < np.asarray(n_valid)[:, None]
+    want = ops.prefill_attention(q, clean, pos, jnp.asarray(valid),
+                                 use_pallas=False)
+
+    def kernel(q, pools, pos, valid):
+        return ops.prefill_attention(q, pools, pos, valid, use_pallas=True)
+
+    if mesh is None:
+        got = kernel(q, junk, pos, jnp.asarray(valid))
+    else:
+        from repro.kvcache.paged import CacheGeometry
+        from repro.launch.mesh import make_test_mesh
+        from repro.launch.shardings import pool_pspec
+        data, model = map(int, mesh.split(","))
+        mesh = make_test_mesh(data=data, model=model)
+        geo = CacheGeometry(num_layers=1, batch=B, page_tokens=T,
+                            hbm_pages=Ph, host_pages=Pe, kv_heads=KH,
+                            head_dim=HD)
+        spec = pool_pspec(geo, mesh)
+        assert spec[3] == "model"
+
+        def kernel_per_shard(*args):
+            with ops.sharded_pools(mesh, spec):
+                return kernel(*args)
+
+        got = jax.jit(kernel_per_shard)(q, junk, pos, jnp.asarray(valid))
+    got = np.asarray(got, np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got[valid],
+                               np.asarray(want, np.float32)[valid], atol=tol)
+    assert np.all(got[~valid] == 0.0)
+
+
+class TestPagedPrefillKernel:
+    @pytest.mark.parametrize("case", PREFILL_CASES,
+                             ids=[c[0] for c in PREFILL_CASES])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_concatenated_pools(self, case, dtype):
+        """Each prefilling lane's slice, over only the pages it holds,
+        gives what the concatenated pools give; junk an earlier request
+        left past a lane's prefix stays invisible."""
+        if case[-1] is None:
+            _prefill_check(case, dtype)
+            return
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                            " --xla_force_host_platform_device_count=4")
+        env["JAX_PLATFORMS"] = "cpu"
+        here = os.path.dirname(os.path.abspath(__file__))
+        env["PYTHONPATH"] = os.pathsep.join(
+            [os.path.join(os.path.dirname(here), "src"), here]
+            + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+        proc = subprocess.run(
+            [sys.executable, __file__, case[0], jnp.dtype(dtype).name],
+            capture_output=True, text=True, env=env, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        assert "PREFILL OK" in proc.stdout, proc.stdout
+
+
 class TestTierMerge:
     @given(st.integers(0, 2**31 - 1))
     @settings(max_examples=20, deadline=None)
@@ -350,3 +452,10 @@ class TestFlashAttention:
         a = flash_attention_jnp(q, k, v, causal=True, q_chunk=64, k_chunk=64)
         b = naive_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+if __name__ == "__main__":
+    # one PREFILL_CASES case in a process with forced host devices
+    _case = {c[0]: c for c in PREFILL_CASES}[sys.argv[1]]
+    _prefill_check(_case, jnp.dtype(sys.argv[2]))
+    print("PREFILL OK")

@@ -1,6 +1,8 @@
 """Serving engine + continuous batcher behaviour."""
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -175,6 +177,63 @@ class TestKernelSlotShare:
         assert share == occupied / slots
         assert 0.0 < share < 1.0
         assert occupied <= walked <= occupied + slack
+
+
+class TestPrefillPages:
+    @staticmethod
+    def _serve_two(model, params):
+        """Two lanes: a 300-token prompt prefilling in 96-token
+        slices, its third across the tier boundary (16 HBM pages of 16
+        tokens), beside a 10-token prompt that decodes from step 2 on."""
+        eng = ServingEngine(model, params, EngineConfig(
+            max_context=512, hbm_fraction=0.25, prefill_chunk=96,
+            telemetry_stride=8, spec=GH200))
+        rng = np.random.default_rng(5)
+        reqs = [Request(rid=0, prompt=rng.integers(0, model.cfg.vocab, 300),
+                        max_new_tokens=4),
+                Request(rid=1, prompt=rng.integers(0, model.cfg.vocab, 10),
+                        max_new_tokens=12)]
+        return eng, eng.serve(reqs, num_slots=2, seed=0)
+
+    def test_share_is_a_hand_count(self, dense_model):
+        """prefill_page_share = Σ pages each prefilling lane's slice
+        attends to / (prefill steps x B x (Ph + Pe)): step 1 both lanes
+        (6 + 1 pages), then the long prompt alone, its slices ending at
+        tokens 192, 288 and 300 (12, 18, 19 pages) while the other lane
+        decodes."""
+        eng, report = self._serve_two(*dense_model)
+        geo = eng.geo
+        assert (geo.batch, geo.page_tokens, geo.hbm_pages, geo.max_pages) \
+            == (2, 16, 16, 48)
+        first = report.chunks[0]
+        assert list(first.prefilling[:5]) == [2, 1, 1, 1, 0]
+        assert list(first.decoding[1:4]) == [1, 1, 1]
+        pages, steps = 6 + 1 + 12 + 18 + 19, 4
+        assert eng.summary()["prefill_page_share"] == \
+            pages / (steps * 2 * 48)
+
+    def test_cli_prints_the_share(self, monkeypatch, capsys):
+        """`launch.serve` prints the share beside the kernel's."""
+        from repro.launch import serve as cli
+        monkeypatch.setattr(cli, "enable_compile_cache", lambda: "")
+        assert cli.main(["--smoke", "--requests", "2", "--prompt-len", "40",
+                         "--new-tokens", "4", "--batch-slots", "2"]) == 0
+        share = re.search(r"prefill page share ([0-9.]+)",
+                          capsys.readouterr().out)
+        assert share and 0.0 < float(share.group(1)) <= 1.0
+
+    def test_paged_kernel_serves_the_same_tokens(self, dense_model,
+                                                 monkeypatch):
+        """The serve stream with prefill attention on the paged Pallas
+        kernel (interpret mode) emits the tokens of the
+        concatenated-pool path."""
+        from repro.kernels import ops
+        _, want = self._serve_two(*dense_model)
+        monkeypatch.setattr(ops, "prefill_attention", functools.partial(
+            ops.prefill_attention, use_pallas=True))
+        _, got = self._serve_two(*dense_model)
+        assert [r.output for r in got.completed] == \
+            [r.output for r in want.completed]
 
 
 class TestDevicePlanner:

@@ -24,6 +24,7 @@ from repro.kernels import ops
 from repro.kernels.paged_attention import (BLOCK_BYTES, BLOCK_VMEM_BYTES,
                                            _vmem_page_bytes, paged_attention,
                                            pages_per_block)
+from repro.kernels.paged_prefill import paged_prefill_attention
 from repro.models.model import Model
 from repro.serving.engine import EngineConfig, ServingEngine
 
@@ -111,6 +112,45 @@ def test_paged_block_rule_fits_vmem(one_chip, tier, pages):
     assert 4 * K * _vmem_page_bytes(T, KH, HD, 2) <= BLOCK_VMEM_BYTES
 
 
+def _kernel_calls(hlo: str, kernel: str) -> list:
+    """The custom calls of Pallas kernel `kernel` (named by its
+    `pallas_call` name) in an HLO module."""
+    return [line.strip() for line in hlo.splitlines()
+            if re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = .*custom-call\(",
+                        line)]
+
+
+#: (cell, Ph, Pe): the prefill kernel's per-chip shapes, 64-token slices:
+#: internlm2's two tiers (8 lanes, 8 KV heads) and the tp4 shard
+#: (granite-8b, 16 lanes, 2 KV heads and 8 query heads a chip)
+PREFILL_TIERS = [("decode-spill", 32, 240), ("chat", 192, 80),
+                 ("tp4", 32, 240)]
+
+
+@pytest.mark.parametrize("cell,hbm,host", PREFILL_TIERS)
+def test_prefill_kernel_compiles_for_v5e(one_chip, cell, hbm, host):
+    """The paged prefill kernel compiles for a v5e at each cell's
+    per-chip heads and tiers."""
+    if cell == "tp4":
+        cfg = configs.get("granite-8b")
+        B, KH, H = 16, cfg.kv_heads // 4, cfg.num_heads // 4
+    else:
+        cfg = configs.get("internlm2-1.8b")
+        B, KH, H = 8, cfg.kv_heads, cfg.num_heads
+    T, HD = cfg.kv_page_tokens, cfg.head_dim
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [sds((B, P, T, KH, HD), jnp.bfloat16)
+             for P in (hbm, hbm, host, host)]
+    lanes = sds((B,), jnp.int32)
+    kernel = functools.partial(paged_prefill_attention, interpret=False)
+    compiled = jax.jit(kernel).lower(
+        sds((B, 64, H, HD), jnp.bfloat16), *pools, lanes, lanes).compile()
+    assert _kernel_calls(compiled.as_text(), "paged_prefill_attention")
+
+
 @pytest.fixture(scope="module")
 def serve_chunk(one_chip, internlm2):
     """The fused serve chunk `chip_smoke.py` runs, compiled once."""
@@ -133,6 +173,21 @@ def test_serve_chunk_compiles_with_kernel_and_fits(serve_chunk):
     mem = serve_chunk.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+def test_serve_chunk_prefill_reads_pages_not_repeated_pools(serve_chunk,
+                                                           internlm2):
+    """Prefill attention runs the paged prefill kernel: no step
+    broadcasts the lanes' concatenated pools to every query head
+    ([B, (Ph + Pe) * T, ...], `repeat_kv`)."""
+    hlo = serve_chunk.as_text()
+    assert _kernel_calls(hlo, "paged_prefill_attention")
+    geo = Model(internlm2).cache_geometry(8, 4096, hbm_fraction=0.25)
+    head = f"[{geo.batch},{geo.max_pages * geo.page_tokens},"
+    wide = [line for line in hlo.splitlines()
+            if (op := re.search(r"\sbroadcast\(", line))
+            and head in line[:op.start()]]
+    assert not wide, wide[:2]
 
 
 def _pool_selects(hlo: str, cfg, kv_heads=None) -> list:
@@ -214,8 +269,9 @@ def _reach(comps: dict, root: str) -> set:
 
 def _decode_branch_pool_copies(hlo: str, cfg, kv_heads: int) -> list:
     """Pool-shaped copies in the serve step's decode `lax.cond` (the one
-    whose branch runs the kernel): in either branch, or in what they
-    call. The carried pools stay in place there, in one layout."""
+    whose branch runs the decode kernel, `paged_attention`): in either
+    branch, or in what they call. The carried pools stay in place
+    there, in one layout."""
     comps = _computations(hlo)
     tail = f",{cfg.kv_page_tokens},{kv_heads},{cfg.head_dim}]"
     found, conds = [], 0
@@ -227,8 +283,8 @@ def _decode_branch_pool_copies(hlo: str, cfg, kv_heads: int) -> list:
                 continue
             branches = [b.strip().lstrip("%") for b in m.group(1).split(",")]
             reach = [_reach(comps, b) for b in branches]
-            if not any("tpu_custom_call" in line
-                       for r in reach for c in r for line in comps[c]):
+            if not any(_kernel_calls("\n".join(comps[c]), "paged_attention")
+                       for r in reach for c in r):
                 continue
             conds += 1
             for c in set().union(*reach):
@@ -269,6 +325,18 @@ def test_granite_tp4_chunk_runs_kernel_per_shard_and_fits(granite_tp4):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+def test_granite_tp4_chunk_runs_prefill_kernel_per_shard(granite_tp4):
+    """Prefill attention runs the paged prefill kernel on each chip's
+    shard: 2 KV heads and 8 query heads a chip, 64-token slices
+    ([B, KH, C * G, HD] out)."""
+    cfg, compiled = granite_tp4
+    calls = _kernel_calls(compiled.as_text(), "paged_prefill_attention")
+    assert calls
+    kh, g = cfg.kv_heads // 4, cfg.q_per_kv
+    shard = f"bf16[16,{kh},{64 * g},{cfg.head_dim}]"
+    assert all(shard in call.split(" custom-call(")[0] for call in calls)
 
 
 def test_granite_tp4_pools_stay_in_place(granite_tp4):
